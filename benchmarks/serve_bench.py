@@ -601,6 +601,8 @@ def main(argv=None) -> int:
                     help="append one 2-slot/5-request interleaved-prefill "
                          "point to --out (keeps prior runs)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         t0 = time.time()

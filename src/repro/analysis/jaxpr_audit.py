@@ -57,6 +57,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from repro.analysis import contracts as C
 from repro.analysis.findings import Finding
@@ -351,16 +352,11 @@ def check_state_dtypes(kind: str, in_dtypes: list, out_dtypes: list
 
 def _jaxpr_subfuns(params):
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
-            yield v
-        elif isinstance(v, (tuple, list)):
-            for x in v:
-                if isinstance(x, jax.core.ClosedJaxpr):
-                    yield x.jaxpr
-                elif isinstance(x, jax.core.Jaxpr):
-                    yield x
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(x, ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, Jaxpr):
+                yield x
 
 
 def _iter_eqns(jaxpr):
@@ -485,7 +481,7 @@ def _slice_jaxpr(jaxpr, out_positions: set) -> tuple[set, set]:
     (by ``id``) are needed to compute ``jaxpr.outvars[i]`` for the given
     positions.
 
-    Descends *precisely* into arity-matched ``pjit`` calls (only the
+    Descends *precisely* into arity-matched ``jit`` calls (only the
     needed inner outputs propagate demand to the outer inputs) and
     *conservatively* into every other call-like primitive — cond / scan /
     while mark all their invars needed and count every gather in every
@@ -495,14 +491,14 @@ def _slice_jaxpr(jaxpr, out_positions: set) -> tuple[set, set]:
     needed = set()
     for i in out_positions:
         v = jaxpr.outvars[i]
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, Literal):
             needed.add(v)
     gathers: set = set()
     for eqn in reversed(jaxpr.eqns):
         if not any(v in needed for v in eqn.outvars):
             continue
         sub = eqn.params.get("jaxpr") \
-            if eqn.primitive.name == "pjit" else None
+            if eqn.primitive.name == "jit" else None
         if (sub is not None
                 and len(sub.jaxpr.invars) == len(eqn.invars)
                 and len(sub.jaxpr.outvars) == len(eqn.outvars)):
@@ -511,7 +507,7 @@ def _slice_jaxpr(jaxpr, out_positions: set) -> tuple[set, set]:
             gathers |= sub_g
             for i in sub_in:
                 v = eqn.invars[i]
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     needed.add(v)
         else:
             if eqn.primitive.name == "gather":
@@ -521,7 +517,7 @@ def _slice_jaxpr(jaxpr, out_positions: set) -> tuple[set, set]:
                     if se.primitive.name == "gather":
                         gathers.add(id(se))
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     needed.add(v)
     invar_positions = {i for i, v in enumerate(jaxpr.invars) if v in needed}
     return invar_positions, gathers

@@ -44,14 +44,12 @@ def sharded_flash_decode(mesh, axis: str, q, k_sharded, v_sharded, valid,
     """shard_map wrapper: q replicated, k/v/valid sharded on seq."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map_compat
-
     def prog(qq, kk, vv, vd):
         o, m, l = local_partial(qq, kk, vv, vd, scale)
         return merge_across(axis, o, m, l)
 
-    return shard_map_compat(
+    return jax.shard_map(
         prog, mesh=mesh,
         in_specs=(P(), P(None, axis, None), P(None, axis, None),
                   P(None, axis)),
-        out_specs=P())(q, k_sharded, v_sharded, valid)
+        out_specs=P(), check_vma=False)(q, k_sharded, v_sharded, valid)

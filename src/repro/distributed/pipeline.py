@@ -81,12 +81,10 @@ def pipeline_apply(layer_fn: Callable, stacked_params, x: jax.Array,
         final = jax.lax.psum(mine, axis)
         return final.reshape((1, B) + x.shape[1:])
 
-    from repro.distributed.sharding import shard_map_compat
-
     spec_p = jax.tree.map(lambda _: P(axis), stacked_params)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         stage_program, mesh=mesh,
-        in_specs=(spec_p, P(axis)), out_specs=P(axis))
+        in_specs=(spec_p, P(axis)), out_specs=P(axis), check_vma=False)
     # replicate x to every stage's input slot (stage 0 uses it; others churn)
     xin = jnp.broadcast_to(x[None], (n_stages,) + x.shape)
     return fn(stacked_params, xin)[0]

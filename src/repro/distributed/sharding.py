@@ -29,28 +29,6 @@ from repro.models.params import axes_to_pspec
 _STATE = threading.local()
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs,
-                     check_replication: bool = False):
-    """``shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.  Both flags
-    mean "verify replication of unmapped outputs" — callers here always pass
-    manually-merged outputs, so the default disables the check.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 check_vma=check_replication)
-        except TypeError:  # pragma: no cover - future flag renames
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_replication)
-
-
 # Logical activation axes: batch, seq (sequence-parallel for long ctx),
 # heads/kv/ff/embed/vocab/experts follow the parameter logical axes.
 def rules_tp(multi_pod: bool, *, seq_data: bool = False) -> dict[str, Any]:

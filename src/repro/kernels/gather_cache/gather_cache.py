@@ -23,85 +23,101 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import default_interpret, round_up
+from repro.kernels.common import default_interpret, pad_dim, round_up
 
-ROW_BLOCK = 8   # rows gathered per grid step (DMA batching factor)
-
-
-def _gather_kernel(ids_ref, cache_ref, out_ref):
-    # cache_ref block: (ROW_BLOCK, D) rows selected by index_map
-    out_ref[...] = cache_ref[...]
+ROW_BLOCK = 8   # output rows per (8, D) output tile
+TILE = 8        # HBM row tiling: the smallest row block the TPU can DMA
 
 
-def _index_map_cache(i, ids_ref):
-    # block index along rows: ids are pre-divided by ROW_BLOCK groups; each
-    # grid step copies ROW_BLOCK consecutive *virtual* rows whose physical
-    # row ids are ids_ref[i*ROW_BLOCK : (i+1)*ROW_BLOCK]. BlockSpec can only
-    # address one block origin per step, so rows are fetched one per step
-    # when indices are arbitrary: ROW_BLOCK=1 path. For ROW_BLOCK>1 we rely
-    # on the id-sorted fast path (see ops.gather_rows sorted=True).
-    return ids_ref[i], 0
+def _place_row(ids_ref, tile_ref, out_ref, scale=None) -> jax.Array:
+    """Grid step ``i`` fetched the TILE-row block holding row ``ids[i]``;
+    return the output tile with that row (times ``scale``, if given) in
+    place ``i % ROW_BLOCK``, in f32.  A max over the block with every
+    other row at -inf selects exactly — it keeps -0.0 and the row's
+    payload — and needs no dynamic sublane indexing."""
+    i = pl.program_id(0)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (TILE, 1), 0)
+    row = jnp.where(sub == ids_ref[i] % TILE,
+                    tile_ref[...].astype(jnp.float32),
+                    -jnp.inf).max(axis=0, keepdims=True)        # [1, D]
+    if scale is not None:
+        row = row * scale                                        # [RB, D]
+    dst = jax.lax.broadcasted_iota(jnp.int32, (ROW_BLOCK, 1), 0)
+    return jnp.where(dst == i % ROW_BLOCK, row,
+                     out_ref[...].astype(jnp.float32))
+
+
+def _row_gather_call(kernel, cache, ids, scales, out_dtype, interpret):
+    """Shared pallas_call plumbing of the row gathers.
+
+    The TPU lowering refuses a ``(1, D)`` block (the second-minor block
+    dim must be a multiple of 8) and a manual DMA of a D=576 row (a
+    slice of the lane-padded minor dim is unaligned).  So grid step
+    ``i`` takes the TILE-row block that holds row ``ids[i]`` through the
+    BlockSpec pipeline (the next block's DMA overlaps this step), and
+    ROW_BLOCK consecutive steps fill one output tile.  Ids are clipped
+    into range and padded to whole output tiles; cache rows are padded
+    to whole TILEs (a no-op for page-pool caches)."""
+    S, D = cache.shape
+    M = ids.shape[0]
+    if interpret is None:
+        interpret = default_interpret()
+    Mp = round_up(max(M, 1), ROW_BLOCK)
+    safe = jnp.pad(jnp.clip(ids, 0, S - 1), (0, Mp - M))
+    cache = pad_dim(cache, 0, round_up(S, TILE))
+    in_specs = [pl.BlockSpec((TILE, D), lambda i, ids_ref: (ids_ref[i] // TILE,
+                                                           0))]
+    args = [cache]
+    if scales is not None:
+        # the gathered rows' scales, widened to f32 outside the kernel
+        # (M values; the TPU vector unit has no f16 loads)
+        in_specs.append(pl.BlockSpec((ROW_BLOCK, 1),
+                                     lambda i, ids_ref: (i // ROW_BLOCK, 0)))
+        args.append(jnp.take(scales, safe, axis=0).astype(jnp.float32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(Mp,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((ROW_BLOCK, D),
+                               lambda i, ids_ref: (i // ROW_BLOCK, 0)),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Mp, D), out_dtype),
+        interpret=interpret,
+    )(safe, *args)
+    return out[:M]
+
+
+def _gather_kernel(ids_ref, tile_ref, out_ref):
+    out_ref[...] = _place_row(ids_ref, tile_ref, out_ref).astype(
+        out_ref.dtype)
 
 
 def gather_rows_kernel(cache: jax.Array, ids: jax.Array,
                        interpret: bool | None = None) -> jax.Array:
     """cache [S, D], ids [M] int32 (negative -> row 0, masked later)
-    -> out [M, D].  One row per grid step, index_map-driven DMA."""
-    S, D = cache.shape
-    M = ids.shape[0]
-    if interpret is None:
-        interpret = default_interpret()
-    safe = jnp.clip(ids, 0, S - 1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(M,),
-        in_specs=[pl.BlockSpec((1, D), _index_map_cache)],
-        out_specs=pl.BlockSpec((1, D), lambda i, ids_ref: (i, 0)),
-    )
-    out = pl.pallas_call(
-        _gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, D), cache.dtype),
-        interpret=interpret,
-    )(safe, cache)
-    return out
+    -> out [M, D].  One requested row per grid step."""
+    return _row_gather_call(_gather_kernel, cache, ids, None, cache.dtype,
+                            interpret)
 
 
-def _gather_dequant_kernel(ids_ref, cache_ref, scales_ref, out_ref):
+def _gather_dequant_kernel(ids_ref, tile_ref, scales_ref, out_ref):
     # fused dequant at block width: the int8/fp8 payload never becomes a
-    # wide tensor outside this (rows, D) tile (contract ESS106)
-    out_ref[...] = (cache_ref[...].astype(jnp.float32)
-                    * scales_ref[...].astype(jnp.float32)
-                    ).astype(out_ref.dtype)
+    # wide tensor outside this tile (contract ESS106)
+    out_ref[...] = _place_row(ids_ref, tile_ref, out_ref,
+                              scales_ref[...]).astype(out_ref.dtype)
 
 
 def gather_rows_dequant_kernel(cache: jax.Array, scales: jax.Array,
                                ids: jax.Array, out_dtype=jnp.bfloat16,
                                interpret: bool | None = None) -> jax.Array:
     """Quantized-tier row gather: cache [S, D] int8/fp8, scales [S, 1],
-    ids [M] int32 -> out [M, D] ``out_dtype``.  One row per grid step —
-    the DMA moves the compressed payload + a scalar scale; dequant runs
-    on the gathered tile inside the kernel."""
-    S, D = cache.shape
-    M = ids.shape[0]
-    if interpret is None:
-        interpret = default_interpret()
-    safe = jnp.clip(ids, 0, S - 1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(M,),
-        in_specs=[pl.BlockSpec((1, D), _index_map_cache),
-                  pl.BlockSpec((1, 1), _index_map_cache)],
-        out_specs=pl.BlockSpec((1, D), lambda i, ids_ref: (i, 0)),
-    )
-    return pl.pallas_call(
-        _gather_dequant_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, D), out_dtype),
-        interpret=interpret,
-    )(safe, cache, scales)
+    ids [M] int32 -> out [M, D] ``out_dtype``.  The DMAs move the
+    compressed payload; dequant runs on the tile inside the kernel."""
+    return _row_gather_call(_gather_dequant_kernel, cache, ids, scales,
+                            out_dtype, interpret)
 
 
 def _gather_block_kernel(base_ref, cache_ref, out_ref):
@@ -137,8 +153,7 @@ def gather_row_blocks_kernel(cache: jax.Array, block_ids: jax.Array,
 
 
 def _gather_block_dequant_kernel(base_ref, cache_ref, scales_ref, out_ref):
-    out_ref[...] = (cache_ref[...].astype(jnp.float32)
-                    * scales_ref[...].astype(jnp.float32)
+    out_ref[...] = (cache_ref[...].astype(jnp.float32) * scales_ref[...]
                     ).astype(out_ref.dtype)
 
 
@@ -150,19 +165,23 @@ def gather_row_blocks_dequant_kernel(cache: jax.Array, scales: jax.Array,
     """Quantized paged variant: whole-page fetch + per-row dequant.
     cache [S, D] int8/fp8 with S % block_rows == 0, scales [S, 1],
     block_ids [NB] -> out [NB*block_rows, D] ``out_dtype``.  Each grid
-    step DMAs one compressed page and its scale column and widens only
-    that (block_rows, D) tile."""
+    step DMAs one compressed page and widens only that (block_rows, D)
+    tile; the requested pages' scale columns (NB*block_rows values) are
+    gathered and widened to f32 outside the kernel — the TPU vector unit
+    has no f16 loads."""
     S, D = cache.shape
     NB = block_ids.shape[0]
     if interpret is None:
         interpret = default_interpret()
     safe = jnp.clip(block_ids, 0, S // block_rows - 1)
+    sc = jnp.take(scales.reshape(S // block_rows, block_rows), safe, axis=0
+                  ).astype(jnp.float32).reshape(NB * block_rows, 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(NB,),
         in_specs=[pl.BlockSpec((block_rows, D), lambda i, ids: (ids[i], 0)),
-                  pl.BlockSpec((block_rows, 1), lambda i, ids: (ids[i], 0))],
+                  pl.BlockSpec((block_rows, 1), lambda i, ids: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, D), lambda i, ids: (i, 0)),
     )
     return pl.pallas_call(
@@ -170,4 +189,4 @@ def gather_row_blocks_dequant_kernel(cache: jax.Array, scales: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NB * block_rows, D), out_dtype),
         interpret=interpret,
-    )(safe, cache, scales)
+    )(safe, cache, sc)

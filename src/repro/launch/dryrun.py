@@ -168,8 +168,6 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
 
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # JAX <= 0.4.x: list per program
-            ca = ca[0] if ca else {}
         coll = collective_bytes(compiled.as_text())
         rec.update({
             "status": "ok",

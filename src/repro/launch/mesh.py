@@ -5,37 +5,20 @@ Multi-pod:  (pod=2, data=16, model=16) = 512 chips.
 
 Functions, not module-level constants — importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS *before* the first jax init).
-
-``make_mesh`` is the version-guarded entry point: newer JAX wants explicit
-``axis_types`` (Auto) for meshes that feed ``shard_map``; JAX <= 0.4.x has
-neither ``jax.sharding.AxisType`` nor the ``axis_types`` kwarg, so the
-helper passes it only when the installed JAX understands it.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    try:
-        if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-            return {}
-    except (TypeError, ValueError):  # pragma: no cover - exotic builds
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with Auto axis types (the meshes here feed
+    ``shard_map`` and sharding-constraint propagation)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_types_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

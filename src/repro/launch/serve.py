@@ -22,6 +22,7 @@ import time
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.params import init_params
 from repro.serving.api import EssEngine, SamplingParams
@@ -47,6 +48,7 @@ def main(argv=None) -> int:
                     help="terminate a stream early at this token id")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     assert cfg.ess.enabled, "serve.py demonstrates the ESS path"

@@ -46,7 +46,8 @@ def smoke_cfg(**ess_overrides):
 def test_chunked_prefill_bitwise_parity(chunk):
     """Host latents, indexer keys and the first sampled token must be
     bit-identical between chunked and one-shot prefill: every chunk stage
-    (score, top-k, gather, attend, ffn) is fixed-shape and per-token."""
+    (score, top-k, gather, attend, ffn) is fixed-shape and per-token.
+    The logits are held to a few-ulp bound (see below)."""
     cfg = smoke_cfg()
     params = init_params(jax.random.key(0), T.model_def(cfg))
     B, S, Smax = 2, 24, 64
@@ -64,8 +65,15 @@ def test_chunked_prefill_bitwise_parity(chunk):
     np.testing.assert_array_equal(np.array(c1.lens), np.array(cc.lens))
     np.testing.assert_array_equal(np.array(greedy(lg1[:, -1])),
                                   np.array(greedy(lgc[:, -1])))
-    # full prefill logits are bitwise equal too (same per-token math)
-    np.testing.assert_array_equal(np.array(lg1), np.array(lgc))
+    # full prefill logits: the final hidden states are bit-identical, but
+    # the f32-accumulated unembed dot runs over B*chunk rows instead of
+    # B*S, and XLA's CPU dot picks its accumulation order by shape — so
+    # the logits may differ in the last f32 ulp (reduction order, not
+    # math; measured at chunk 7: 1.55 ulps of the largest logit)
+    lg1, lgc = np.array(lg1), np.array(lgc)
+    np.testing.assert_allclose(
+        lgc, lg1, rtol=0,
+        atol=4 * np.finfo(np.float32).eps * np.abs(lg1).max())
 
 
 def test_serve_session_chunked_prefill_matches_oneshot_first_token():

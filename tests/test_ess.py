@@ -127,9 +127,14 @@ def test_engine_prefill_chunked_matches_train():
     assert diff.max() < 5e-2
     assert diff.mean() < 2e-3
     # chunked prefill streams through the same engine: bit-identical
+    # hidden states; the unembed dot's f32 accumulation order follows
+    # its row count (XLA CPU), so the logits agree to the last few ulps
     lg7, _ = E.ess_prefill(params, cfg, toks, pos, 40, do_warmup=False,
                            prefill_chunk=7)
-    np.testing.assert_array_equal(np.array(lg7), np.array(lg))
+    lg = np.array(lg)
+    np.testing.assert_allclose(
+        np.array(lg7), lg, rtol=0,
+        atol=4 * np.finfo(np.float32).eps * np.abs(lg).max())
 
 
 def test_intra_layer_similarity_eq1():
