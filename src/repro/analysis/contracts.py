@@ -180,3 +180,28 @@ ESS106_WIDE_DTYPES = ("bfloat16", "float16", "float32")
 # the packet).
 PACK_BUDGET_PER_MIGRATION = 1
 PACK_SITE = "repro/cluster/kv_transfer.py::pack_migration"
+
+# ---------------------------------------------------------------------------
+# Stage names in the profiler trace
+# ---------------------------------------------------------------------------
+
+# Device scopes (``jax.named_scope``) of the decode and prefill-chunk
+# programs; every op of a decode round lies under exactly one of them
+# (repro.analysis.hlo_scopes maps an optimized HLO module onto them).
+# The MTP draft and accept stages of the spec round are not named yet.
+DEVICE_SCOPES = ("ess.embed", "ess.indexer", "ess.topk", "ess.pool",
+                 "ess.miss_gather", "ess.attend", "ess.spill",
+                 "ess.prefetch", "ess.ffn", "ess.head")
+
+# XLA module name of each round kind (the jitted round function's name)
+ROUND_MODULES = {"decode": "jit_decode_round", "spec": "jit_spec_round",
+                 "prefill": "jit_prefill_chunk"}
+
+# Host spans (jax.profiler annotations) of ServeSession.step_round: the
+# round itself (a StepTraceAnnotation whose step_num is the round index)
+# and its stages in the order they run.  ``ess.prefill`` appears only in
+# rounds that run a prefill chunk, the decode stages only in rounds with
+# an active slot.
+ROUND_SPAN = "ess.round"
+ROUND_STAGE_SPANS = ("ess.admit", "ess.prefill", "ess.plan", "ess.launch",
+                     "ess.fetch", "ess.commit", "ess.finish")

@@ -132,19 +132,22 @@ def _topk_and_lookup(idx_p, cfg, x_norm, state, idx_keys, lens, slot_mask):
     K = min(cfg.dsa.index_topk, S)
     M_env = max(1, int(cfg.ess.max_miss_ratio * K)) * Q
 
-    iq = M.indexer_query(idx_p, x_norm)
-    sc = M.indexer_scores(iq, idx_keys)                          # [B,Q,S]
-    qlens = lens[:, None] if lens.ndim == 1 else lens            # [B,Q]
-    valid_s = jnp.arange(S)[None, None, :] < qlens[:, :, None]   # [B,Q,S]
-    valid_s = jnp.broadcast_to(valid_s, (B, Q, S))
-    ids = M.topk_ids(sc, K, valid_s)                             # [B,Q,K]
-    req_valid = jnp.take_along_axis(valid_s, ids, axis=2)
-    flat_ids = ids.reshape(B, Q * K)
-    flat_valid = req_valid.reshape(B, Q * K)
-    # one query's top-k is duplicate-free; only the Q>1 flattening can
-    # request the same position twice (skip the O(K^2) dedup at Q=1)
-    pool, lk, stats = LP.lookup(state.pool, flat_ids, flat_valid, M_env,
-                                slot_mask=slot_mask, dedup=Q > 1)
+    with jax.named_scope("ess.indexer"):
+        iq = M.indexer_query(idx_p, x_norm)
+        sc = M.indexer_scores(iq, idx_keys)                      # [B,Q,S]
+    with jax.named_scope("ess.topk"):
+        qlens = lens[:, None] if lens.ndim == 1 else lens        # [B,Q]
+        valid_s = jnp.arange(S)[None, None, :] < qlens[:, :, None]
+        valid_s = jnp.broadcast_to(valid_s, (B, Q, S))           # [B,Q,S]
+        ids = M.topk_ids(sc, K, valid_s)                         # [B,Q,K]
+        req_valid = jnp.take_along_axis(valid_s, ids, axis=2)
+    with jax.named_scope("ess.pool"):
+        flat_ids = ids.reshape(B, Q * K)
+        flat_valid = req_valid.reshape(B, Q * K)
+        # one query's top-k is duplicate-free; only the Q>1 flattening can
+        # request the same position twice (skip the O(K^2) dedup at Q=1)
+        pool, lk, stats = LP.lookup(state.pool, flat_ids, flat_valid, M_env,
+                                    slot_mask=slot_mask, dedup=Q > 1)
     return pool, lk, stats, ids, req_valid, K, M_env, sc
 
 
@@ -159,43 +162,48 @@ def _finish_attention(mla_p, cfg, x_norm, positions, pool, lk, ids,
     bit-identical outputs (the async-offload parity bar).  Returns
     ``(out, pool-after-admit)``; the caller ticks the clock."""
     B, Q, _ = x_norm.shape
-    q_comb = M.absorbed_query(mla_p, cfg, x_norm, positions)     # [B,Q,H,D]
+    with jax.named_scope("ess.attend"):
+        q_comb = M.absorbed_query(mla_p, cfg, x_norm, positions)  # [B,Q,H,D]
+        hit = lk.hit.reshape(B, Q, K)
+        if overlap == "none":
+            # single attention over the union: every row depends on the
+            # fetch
+            with jax.named_scope("ess.pool"):
+                rows_hit, _ = LP.gather_resident(pool, lk.slot, lk.hit)
+            # misses: place fetched rows back at their request positions
+            fr = jnp.where(lk.miss_rank[..., None] < M_env,
+                           jnp.take_along_axis(
+                               fetched, jnp.clip(lk.miss_rank, 0, M_env - 1)
+                               [..., None], axis=1), 0)
+            rows = jnp.where(lk.hit[..., None], rows_hit, fr)
+            valid = (lk.hit | (lk.miss_rank < M_env)) & \
+                (ids.reshape(B, Q * K) >= 0)
+            part = _attend_rows(q_comb, rows.reshape(B, Q, K, -1),
+                                valid.reshape(B, Q, K), cfg, use_kernel)
+        else:
+            # Attn0: pool-resident rows only (independent of the fetch)
+            with jax.named_scope("ess.pool"):
+                rows0, _ = LP.gather_resident(pool, lk.slot, lk.hit)
+            p0 = _attend_rows(q_comb, rows0.reshape(B, Q, K, -1),
+                              hit & req_valid.reshape(B, Q, K).astype(bool),
+                              cfg, use_kernel)
+            # Attn1: fetched rows (waits on the H2D copy); at Q>1 each
+            # query attends only the rows it requested (at Q=1 that set is
+            # exactly the whole miss buffer — skip the scatter)
+            mvalid = (lk.miss_ids >= 0)
+            fvalid = _fetch_valid(lk, B, Q, K, M_env) & mvalid[:, None] \
+                if Q > 1 else jnp.broadcast_to(mvalid[:, None],
+                                               (B, Q, M_env))
+            p1 = _attend_rows(q_comb, fetched[:, None].repeat(Q, 1)
+                              if Q > 1 else fetched[:, None],
+                              fvalid, cfg, use_kernel)
+            part = M.merge_partials(p0, p1)
 
-    hit = lk.hit.reshape(B, Q, K)
-    if overlap == "none":
-        # single attention over the union: every row depends on the fetch
-        rows_hit, _ = LP.gather_resident(pool, lk.slot, lk.hit)
-        # misses: place fetched rows back at their request positions
-        fr = jnp.where(lk.miss_rank[..., None] < M_env,
-                       jnp.take_along_axis(
-                           fetched, jnp.clip(lk.miss_rank, 0, M_env - 1)
-                           [..., None], axis=1), 0)
-        rows = jnp.where(lk.hit[..., None], rows_hit, fr)
-        valid = (lk.hit | (lk.miss_rank < M_env)) & \
-            (ids.reshape(B, Q * K) >= 0)
-        part = _attend_rows(q_comb, rows.reshape(B, Q, K, -1),
-                            valid.reshape(B, Q, K), cfg, use_kernel)
-    else:
-        # Attn0: pool-resident rows only (independent of the fetch)
-        rows0, _ = LP.gather_resident(pool, lk.slot, lk.hit)
-        p0 = _attend_rows(q_comb, rows0.reshape(B, Q, K, -1),
-                          hit & req_valid.reshape(B, Q, K).astype(bool),
-                          cfg, use_kernel)
-        # Attn1: fetched rows (waits on the H2D copy); at Q>1 each query
-        # attends only the rows it requested (at Q=1 that set is exactly
-        # the whole miss buffer — skip the scatter)
-        mvalid = (lk.miss_ids >= 0)
-        fvalid = _fetch_valid(lk, B, Q, K, M_env) & mvalid[:, None] \
-            if Q > 1 else jnp.broadcast_to(mvalid[:, None], (B, Q, M_env))
-        p1 = _attend_rows(q_comb, fetched[:, None].repeat(Q, 1)
-                          if Q > 1 else fetched[:, None],
-                          fvalid, cfg, use_kernel)
-        part = M.merge_partials(p0, p1)
+        out_lat = M.finalize_partial(part, x_norm.dtype)
+        out = M.output_proj(mla_p, cfg, out_lat)
 
-    out_lat = M.finalize_partial(part, x_norm.dtype)
-    out = M.output_proj(mla_p, cfg, out_lat)
-
-    pool = LP.admit(pool, lk.miss_ids, fetched, slot_mask=slot_mask)
+    with jax.named_scope("ess.pool"):
+        pool = LP.admit(pool, lk.miss_ids, fetched, slot_mask=slot_mask)
     return out, pool
 
 
@@ -205,16 +213,17 @@ def _da_or_none(mla_p, idx_p, cfg, x_norm, positions, state, idx_keys, lens,
         idx_p, cfg, x_norm, state, idx_keys, lens, slot_mask)
 
     # ---- issue the H2D fetch as early as possible (DA overlap) ----
-    fetched = offload.gather_tier_rows(state.host_latent, state.host_scales,
-                                       lk.miss_ids,
-                                       layer=state.layer,
-                                       batch_offset=state.batch_offset,
-                                       block_table=state.block_table)
+    with jax.named_scope("ess.miss_gather"):
+        fetched = offload.gather_tier_rows(
+            state.host_latent, state.host_scales, lk.miss_ids,
+            layer=state.layer, batch_offset=state.batch_offset,
+            block_table=state.block_table)
 
     out, pool = _finish_attention(mla_p, cfg, x_norm, positions, pool, lk,
                                   ids, req_valid, fetched, K, M_env,
                                   overlap, use_kernel, slot_mask)
-    pool = LP.tick(pool)
+    with jax.named_scope("ess.pool"):
+        pool = LP.tick(pool)
     new_state = state._replace(pool=pool)
     return out, new_state, ESSStats(stats.hits, stats.misses, stats.overflow)
 
@@ -270,7 +279,8 @@ def ess_sparse_attention_staged(mla_p: dict, idx_p: dict, cfg: ArchConfig,
     pool, lk, stats, ids, req_valid, K, M_env, sc = _topk_and_lookup(
         idx_p, cfg, x_norm, state, idx_keys, lens, slot_mask)
 
-    mvalid = lk.miss_ids >= 0
+    with jax.named_scope("ess.miss_gather"):
+        mvalid = lk.miss_ids >= 0
     D = new_rows.shape[-1]
 
     def _source_rows():
@@ -301,22 +311,27 @@ def ess_sparse_attention_staged(mla_p: dict, idx_p: dict, cfg: ArchConfig,
                 smatch.sum(-1).astype(jnp.int32),
                 unmatched.sum(-1).astype(jnp.int32))
 
-    fetched, s_hits, s_unm = jax.lax.cond(
-        jnp.any(mvalid), _source_rows,
-        lambda: (jnp.zeros((B, M_env, D), new_rows.dtype),
-                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32)))
+    with jax.named_scope("ess.miss_gather"):
+        fetched, s_hits, s_unm = jax.lax.cond(
+            jnp.any(mvalid), _source_rows,
+            lambda: (jnp.zeros((B, M_env, D), new_rows.dtype),
+                     jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32)))
 
     out, pool = _finish_attention(mla_p, cfg, x_norm, positions, pool, lk,
                                   ids, req_valid, fetched, K, M_env,
                                   "da" if overlap == "dba" else overlap,
                                   use_kernel, slot_mask)
-    pool = LP.tick(pool)
+    with jax.named_scope("ess.pool"):
+        pool = LP.tick(pool)
 
-    qlast = lens[:, -1] if lens.ndim == 2 else lens
-    liv = live.astype(jnp.int32)
+    # this layer's plan-stage signal and prefetch counters
+    with jax.named_scope("ess.prefetch"):
+        qlast = lens[:, -1] if lens.ndim == 2 else lens
+        liv = live.astype(jnp.int32)
+        sig = (sc[:, -1], qlast, pool.slot_of)
+        pf = (s_hits * liv, s_unm * liv)
     return out, state._replace(pool=pool), \
-        ESSStats(stats.hits, stats.misses, stats.overflow), \
-        (sc[:, -1], qlast, pool.slot_of), (s_hits * liv, s_unm * liv)
+        ESSStats(stats.hits, stats.misses, stats.overflow), sig, pf
 
 
 def _dba(mla_p, idx_p, cfg, x_norm, positions, state, idx_keys, lens,
@@ -345,19 +360,17 @@ def _dba(mla_p, idx_p, cfg, x_norm, positions, state, idx_keys, lens,
     # half-1 indexer + fetch issue
     p0_pool, lk0, st0, ids0, rv0, K, M_env, _ = _topk_and_lookup(
         idx_p, cfg, x_norm[:h], s0, idx_keys[:h], lens[:h], sm0)
-    fetched0 = offload.gather_tier_rows(s0.host_latent, s0.host_scales,
-                                        lk0.miss_ids,
-                                        layer=s0.layer,
-                                        batch_offset=s0.batch_offset,
-                                        block_table=s0.block_table)
+    with jax.named_scope("ess.miss_gather"):
+        fetched0 = offload.gather_tier_rows(
+            s0.host_latent, s0.host_scales, lk0.miss_ids, layer=s0.layer,
+            batch_offset=s0.batch_offset, block_table=s0.block_table)
     # half-2 indexer (independent of fetched0 -> overlaps the copy)
     p1_pool, lk1, st1, ids1, rv1, _, _, _ = _topk_and_lookup(
         idx_p, cfg, x_norm[h:], s1, idx_keys[h:], lens[h:], sm1)
-    fetched1 = offload.gather_tier_rows(s1.host_latent, s1.host_scales,
-                                        lk1.miss_ids,
-                                        layer=s1.layer,
-                                        batch_offset=s1.batch_offset,
-                                        block_table=s1.block_table)
+    with jax.named_scope("ess.miss_gather"):
+        fetched1 = offload.gather_tier_rows(
+            s1.host_latent, s1.host_scales, lk1.miss_ids, layer=s1.layer,
+            batch_offset=s1.batch_offset, block_table=s1.block_table)
 
     out0, ns0 = _finish_half(mla_p, cfg, x_norm[:h], positions[:h], p0_pool,
                              lk0, ids0, rv0, fetched0, s0, K, M_env,
@@ -369,29 +382,36 @@ def _dba(mla_p, idx_p, cfg, x_norm, positions, state, idx_keys, lens,
     pool = LP.PoolState(*(jnp.concatenate([a, b], 0) if a.ndim > 0 else a
                           for a, b in zip(ns0.pool, ns1.pool)))
     pool = pool._replace(step=state.pool.step)
-    pool = LP.tick(pool)
-    out = jnp.concatenate([out0, out1], 0)
-    hits = jnp.concatenate([st0.hits, st1.hits], 0)
-    misses = jnp.concatenate([st0.misses, st1.misses], 0)
-    ovf = jnp.concatenate([st0.overflow, st1.overflow], 0)
+    with jax.named_scope("ess.pool"):
+        pool = LP.tick(pool)
+    with jax.named_scope("ess.attend"):
+        out = jnp.concatenate([out0, out1], 0)
+    with jax.named_scope("ess.pool"):
+        hits = jnp.concatenate([st0.hits, st1.hits], 0)
+        misses = jnp.concatenate([st0.misses, st1.misses], 0)
+        ovf = jnp.concatenate([st0.overflow, st1.overflow], 0)
     return out, state._replace(pool=pool), ESSStats(hits, misses, ovf)
 
 
 def _finish_half(mla_p, cfg, x_norm, positions, pool, lk, ids, req_valid,
                  fetched, st, K, M_env, use_kernel, slot_mask=None):
     B, Q, _ = x_norm.shape
-    q_comb = M.absorbed_query(mla_p, cfg, x_norm, positions)
-    hit = lk.hit.reshape(B, Q, K)
-    rows0, _ = LP.gather_resident(pool, lk.slot, lk.hit)
-    p0 = _attend_rows(q_comb, rows0.reshape(B, Q, K, -1),
-                      hit & req_valid.astype(bool), cfg, use_kernel)
-    mvalid = lk.miss_ids >= 0
-    fvalid = _fetch_valid(lk, B, Q, K, M_env) & mvalid[:, None] \
-        if Q > 1 else jnp.broadcast_to(mvalid[:, None], (B, Q, M_env))
-    p1 = _attend_rows(q_comb, fetched[:, None].repeat(Q, 1) if Q > 1
-                      else fetched[:, None],
-                      fvalid, cfg, use_kernel)
-    part = M.merge_partials(p0, p1)
-    out = M.output_proj(mla_p, cfg, M.finalize_partial(part, x_norm.dtype))
-    pool = LP.admit(pool, lk.miss_ids, fetched, slot_mask=slot_mask)
+    with jax.named_scope("ess.attend"):
+        q_comb = M.absorbed_query(mla_p, cfg, x_norm, positions)
+        hit = lk.hit.reshape(B, Q, K)
+        with jax.named_scope("ess.pool"):
+            rows0, _ = LP.gather_resident(pool, lk.slot, lk.hit)
+        p0 = _attend_rows(q_comb, rows0.reshape(B, Q, K, -1),
+                          hit & req_valid.astype(bool), cfg, use_kernel)
+        mvalid = lk.miss_ids >= 0
+        fvalid = _fetch_valid(lk, B, Q, K, M_env) & mvalid[:, None] \
+            if Q > 1 else jnp.broadcast_to(mvalid[:, None], (B, Q, M_env))
+        p1 = _attend_rows(q_comb, fetched[:, None].repeat(Q, 1) if Q > 1
+                          else fetched[:, None],
+                          fvalid, cfg, use_kernel)
+        part = M.merge_partials(p0, p1)
+        out = M.output_proj(mla_p, cfg,
+                            M.finalize_partial(part, x_norm.dtype))
+    with jax.named_scope("ess.pool"):
+        pool = LP.admit(pool, lk.miss_ids, fetched, slot_mask=slot_mask)
     return out, st._replace(pool=pool)

@@ -48,6 +48,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.cache import latent_cache as LC
 from repro.configs.base import ArchConfig
@@ -94,12 +95,26 @@ def generic_decode(params, cfg: ArchConfig, tokens, positions, caches, **kw):
 # ESS path (DSA + MLA + offload)
 # ---------------------------------------------------------------------------
 
+class _LayerParams:
+    """One layer's slice of the stacked weights, cut group by group on
+    first use, so that each slice carries the ``ess.*`` scope of the
+    stage that reads it."""
+
+    def __init__(self, stack: dict, index: int):
+        self._stack, self._index, self._cut = stack, index, {}
+
+    def __getitem__(self, key: str):
+        if key not in self._cut:
+            self._cut[key] = jax.tree.map(lambda a: a[self._index],
+                                          self._stack[key])
+        return self._cut[key]
+
+
 def _layer_params(params, cfg: ArchConfig, layer: int):
     nd = cfg.moe.first_dense_layers if cfg.moe else 0
     if layer < nd:
-        return jax.tree.map(lambda a: a[layer], params["dense_layers"]), False
-    return jax.tree.map(lambda a: a[layer - nd], params["layers"]), \
-        cfg.moe is not None
+        return _LayerParams(params["dense_layers"], layer), False
+    return _LayerParams(params["layers"], layer - nd), cfg.moe is not None
 
 
 def _overlap_for_layer(cfg: ArchConfig, layer: int,
@@ -144,68 +159,79 @@ def ess_decode(params, cfg: ArchConfig, tokens, positions,
     the pre-pipeline graph.
     """
     B, Q = tokens.shape
-    x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
-    x = shard(x, "batch", None, "embed_act")
-    lens = caches.lens
-    if slot_mask is None:
-        live = jnp.ones((B,), bool)
-    else:
-        live = slot_mask
-    new_lens = lens + Q * live.astype(lens.dtype)
-    bi = jnp.arange(B)[:, None]
-    widx = jnp.where(live[:, None],
-                     lens[:, None] + jnp.arange(Q)[None, :], -1)  # [B,Q]
-    # per-query attention horizon: draft q sees positions <= its own (the
-    # Q window stays causal — without this every draft would attend to
-    # entries appended by later drafts, breaking parity with sequential
-    # Q=1 steps); masked slots contribute no valid entries at all
-    attn_lens = widx + 1                                          # [B,Q]
+    with jax.named_scope("ess.embed"):
+        x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
+        x = shard(x, "batch", None, "embed_act")
+        lens = caches.lens
+        if slot_mask is None:
+            live = jnp.ones((B,), bool)
+        else:
+            live = slot_mask
+        new_lens = lens + Q * live.astype(lens.dtype)
+        bi = jnp.arange(B)[:, None]
+        widx = jnp.where(live[:, None],
+                         lens[:, None] + jnp.arange(Q)[None, :], -1)  # [B,Q]
+        # per-query attention horizon: draft q sees positions <= its own
+        # (the Q window stays causal — without this every draft would
+        # attend to entries appended by later drafts, breaking parity with
+        # sequential Q=1 steps); masked slots contribute no valid entries
+        attn_lens = widx + 1                                      # [B,Q]
 
     host_latent = caches.host_latent
     host_scales = caches.host_scales   # per-row scales of a quantized tier
     ikeys_all = caches.ikeys
     pools = caches.pools
-    hits = misses = ovf = jnp.zeros((B,), jnp.int32)
+    with jax.named_scope("ess.pool"):
+        hits = misses = ovf = jnp.zeros((B,), jnp.int32)
     lat_stack: list[jax.Array] = []    # staged mode: deferred D2H spill
     scale_stack: list[jax.Array] = []  # staged+quantized: the rows' scales
     plan_sigs: list[tuple] = []        # staged mode: per-layer plan signal
-    pf_h = pf_m = pf_w = jnp.zeros((B,), jnp.int32)
+    with jax.named_scope("ess.prefetch"):
+        pf_h = pf_m = pf_w = jnp.zeros((B,), jnp.int32)
 
     for layer in range(cfg.num_layers):
         lp, is_moe = _layer_params(params, cfg, layer)
-        h = L.rmsnorm(lp["ln1"], x, cfg.norm_eps)
+        with jax.named_scope("ess.attend"):
+            h = L.rmsnorm(lp["ln1"], x, cfg.norm_eps)
 
         # --- append: indexer key (device) + latent entry (host, D2H) -----
-        new_ik = M.indexer_keys(lp["indexer"], h)                # [B,Q,Di]
-        S_ik = ikeys_all[layer].shape[1]
-        ik_widx = jnp.where(widx >= 0, widx, S_ik)               # OOB -> drop
-        ik_l = ikeys_all[layer].at[bi, ik_widx].set(
-            new_ik.astype(ikeys_all[layer].dtype), mode="drop")
+        with jax.named_scope("ess.indexer"):
+            new_ik = M.indexer_keys(lp["indexer"], h)            # [B,Q,Di]
+            S_ik = ikeys_all[layer].shape[1]
+            ik_widx = jnp.where(widx >= 0, widx, S_ik)           # OOB -> drop
+            ik_l = ikeys_all[layer].at[bi, ik_widx].set(
+                new_ik.astype(ikeys_all[layer].dtype), mode="drop")
         ikeys_all = ikeys_all[:layer] + (ik_l,) + ikeys_all[layer + 1:]
-        new_lat = M.latent_entries(lp["mla"], cfg, h, positions) # [B,Q,D]
-        if staged is None:
-            # masked slots' gating is already folded into widx (-1 drops)
-            host_latent, host_scales = offload.scatter_tier_rows(
-                host_latent, host_scales, widx, new_lat, slot_mask=None,
-                layer=layer, block_table=caches.block_tables)
-        elif host_scales is None:
-            # pipelined: spill deferred to the commit stage (one stacked
-            # scatter after the loop); keep the host-dtype rows at hand so
-            # same-round misses are served from the live activations
-            lat_stack.append(new_lat.astype(host_latent.dtype))
-            own_rows = lat_stack[-1]
-        else:
-            # pipelined + quantized: quantize ONCE here and commit the
-            # exact (q, s) pair later — the own-row bypass serves
-            # dequant(q, s), which is bit-identical to the synchronous
-            # scatter→gather round trip (re-quantizing dequantized rows
-            # would land on a different grid point)
-            q_lat, s_lat = cmp.quantize_rows(new_lat, host_latent.dtype)
-            lat_stack.append(q_lat)
-            scale_stack.append(s_lat)
-            own_rows = cmp.dequantize_rows(q_lat, s_lat, cfg.param_dtype)
+        with jax.named_scope("ess.attend"):
+            new_lat = M.latent_entries(lp["mla"], cfg, h, positions)
+        with jax.named_scope("ess.spill"):
+            if staged is None:
+                # masked slots' gating is already folded into widx (-1
+                # drops)
+                host_latent, host_scales = offload.scatter_tier_rows(
+                    host_latent, host_scales, widx, new_lat, slot_mask=None,
+                    layer=layer, block_table=caches.block_tables)
+            elif host_scales is None:
+                # pipelined: spill deferred to the commit stage (one
+                # stacked scatter after the loop); keep the host-dtype rows
+                # at hand so same-round misses are served from the live
+                # activations
+                lat_stack.append(new_lat.astype(host_latent.dtype))
+                own_rows = lat_stack[-1]
+            else:
+                # pipelined + quantized: quantize ONCE here and commit the
+                # exact (q, s) pair later — the own-row bypass serves
+                # dequant(q, s), which is bit-identical to the synchronous
+                # scatter→gather round trip (re-quantizing dequantized
+                # rows would land on a different grid point)
+                q_lat, s_lat = cmp.quantize_rows(new_lat, host_latent.dtype)
+                lat_stack.append(q_lat)
+                scale_stack.append(s_lat)
+                own_rows = cmp.dequantize_rows(q_lat, s_lat, cfg.param_dtype)
 
         # --- ESS sparse attention (fetch ∥ Attn0, Attn1, merge, admit) ---
+        # (core.overlap scopes its own stages: indexer, topk, pool,
+        # miss_gather, attend)
         st = ESSLayerState(pools[layer], host_latent, layer,
                            block_table=caches.block_tables,
                            host_scales=host_scales)
@@ -216,34 +242,40 @@ def ess_decode(params, cfg: ArchConfig, tokens, positions,
                 attn_lens, overlap=ov, use_kernel=use_kernel,
                 slot_mask=live)
         else:
-            sc_l = None if len(staged) < 3 or staged[2] is None \
-                else staged[2][layer]
+            with jax.named_scope("ess.miss_gather"):
+                sc_l = None if len(staged) < 3 or staged[2] is None \
+                    else staged[2][layer]
+                ids_l, rows_l = staged[0][layer], staged[1][layer]
             attn, st2, stats, sig, pf = ess_sparse_attention_staged(
                 lp["mla"], lp["indexer"], cfg, h, positions, st, ik_l,
                 attn_lens, new_rows=own_rows, widx=widx,
-                staged_ids_l=staged[0][layer],
-                staged_rows_l=staged[1][layer],
+                staged_ids_l=ids_l, staged_rows_l=rows_l,
                 staged_scales_l=sc_l, overlap=ov,
                 use_kernel=use_kernel, slot_mask=live)
             plan_sigs.append(sig)
-            pf_h, pf_m = pf_h + pf[0], pf_m + pf[1]
+            with jax.named_scope("ess.prefetch"):
+                pf_h, pf_m = pf_h + pf[0], pf_m + pf[1]
         pools = pools[:layer] + (st2.pool,) + pools[layer + 1:]
-        x = x + attn
+        with jax.named_scope("ess.attend"):
+            x = x + attn
 
         # --- ffn ----------------------------------------------------------
-        h2 = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
-        if is_moe:
-            f, _ = MoE.moe_apply(lp["ffn"], cfg, h2)
-        else:
-            f = L.mlp(lp["ffn"], h2, cfg.act)
-        x = x + f
-        hits = hits + stats.hits
-        misses = misses + stats.misses
-        ovf = ovf + stats.overflow
+        with jax.named_scope("ess.ffn"):
+            h2 = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
+            if is_moe:
+                f, _ = MoE.moe_apply(lp["ffn"], cfg, h2)
+            else:
+                f = L.mlp(lp["ffn"], h2, cfg.act)
+            x = x + f
+        with jax.named_scope("ess.pool"):
+            hits = hits + stats.hits
+            misses = misses + stats.misses
+            ovf = ovf + stats.overflow
 
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.unembed(params.get("unembed", params.get("embed")), x,
-                       cap=cfg.logit_softcap)
+    with jax.named_scope("ess.head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = L.unembed(params.get("unembed", params.get("embed")), x,
+                           cap=cfg.logit_softcap)
     stats_out = {"hits": hits, "misses": misses, "overflow": ovf,
                  "hidden": x}
     if staged is not None:
@@ -251,13 +283,14 @@ def ess_decode(params, cfg: ArchConfig, tokens, positions,
         # (quantized tier: the layer loop's precomputed (q, s) pairs land
         # verbatim — payload and scale plane in one stacked scatter each,
         # so the PCIe bytes stay at compressed width) -----------------
-        host_latent = offload.scatter_from_slab(
-            host_latent, widx, jnp.stack(lat_stack), slot_mask=None,
-            block_table=caches.block_tables)
-        if host_scales is not None:
-            host_scales = offload.scatter_from_slab(
-                host_scales, widx, jnp.stack(scale_stack), slot_mask=None,
+        with jax.named_scope("ess.spill"):
+            host_latent = offload.scatter_from_slab(
+                host_latent, widx, jnp.stack(lat_stack), slot_mask=None,
                 block_table=caches.block_tables)
+            if host_scales is not None:
+                host_scales = offload.scatter_from_slab(
+                    host_scales, widx, jnp.stack(scale_stack),
+                    slot_mask=None, block_table=caches.block_tables)
         # --- plan stage: stage next round's predicted rows (after the
         # commit, so predictions may target rows appended this round).
         # The whole plan is gated on the round having *missed at all*: a
@@ -321,10 +354,11 @@ def ess_decode(params, cfg: ArchConfig, tokens, positions,
 
         keep = (lambda: (staged[0], staged[1])) if st_scales is None \
             else (lambda: (staged[0], staged[1], st_scales))
-        plan_out = jax.lax.cond(jnp.any(misses > 0), _plan, keep)
+        with jax.named_scope("ess.prefetch"):
+            plan_out = jax.lax.cond(jnp.any(misses > 0), _plan, keep)
+            pf_w = ((staged[0] >= 0).sum((0, 2)).astype(jnp.int32)
+                    * live.astype(jnp.int32) - pf_h)
         pred, slab_rows = plan_out[0], plan_out[1]
-        pf_w = ((staged[0] >= 0).sum((0, 2)).astype(jnp.int32)
-                * live.astype(jnp.int32) - pf_h)
         stats_out.update(staged_ids=pred, staged_rows=slab_rows,
                          pf_hits=pf_h, pf_misses=pf_m, pf_wasted=pf_w)
         if st_scales is not None:
@@ -386,112 +420,125 @@ def ess_prefill_chunk(params, cfg: ArchConfig, tokens, positions,
     else:
         b0, Bc = slot, 1
     C = tokens.shape[1]
-    start = jax.lax.dynamic_slice_in_dim(caches.lens, b0, Bc)    # [Bc]
-    x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
-    x = shard(x, "batch", None, "embed_act")
-    bi = jnp.arange(Bc)[:, None]
-    nv = jnp.asarray(C if n_valid is None else n_valid, jnp.int32)
-    cpos = jnp.arange(C, dtype=jnp.int32)
-    widx = jnp.where(cpos[None, :] < nv,
-                     start[:, None] + cpos[None, :], -1)         # [Bc,C]
-
     host = caches.host_latent
     ikeys_all = caches.ikeys
     S = ikeys_all[0].shape[1]
     K = min(cfg.dsa.index_topk, S)
-    causal = jnp.arange(S)[None, None, :] <= widx[:, :, None]    # [Bc,C,S]
+    with jax.named_scope("ess.embed"):
+        start = jax.lax.dynamic_slice_in_dim(caches.lens, b0, Bc)  # [Bc]
+        x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
+        x = shard(x, "batch", None, "embed_act")
+        bi = jnp.arange(Bc)[:, None]
+        nv = jnp.asarray(C if n_valid is None else n_valid, jnp.int32)
+        cpos = jnp.arange(C, dtype=jnp.int32)
+        widx = jnp.where(cpos[None, :] < nv,
+                         start[:, None] + cpos[None, :], -1)     # [Bc,C]
+        causal = jnp.arange(S)[None, None, :] <= widx[:, :, None]  # [Bc,C,S]
     lat_stack = []
     scale_stack = []           # quantized tier: the chunk rows' scales
     tails = []
 
     for layer in range(cfg.num_layers):
         lp, is_moe = _layer_params(params, cfg, layer)
-        h = L.rmsnorm(lp["ln1"], x, cfg.norm_eps)
+        with jax.named_scope("ess.attend"):
+            h = L.rmsnorm(lp["ln1"], x, cfg.norm_eps)
         if collect_tail:
             tails.append(h[:, -collect_tail:])
 
         # --- append indexer keys (device) + chunk latents (deferred D2H) --
-        ik_full = ikeys_all[layer]
-        ik_slot = jax.lax.dynamic_slice_in_dim(ik_full, b0, Bc, axis=0)
-        new_ik = M.indexer_keys(lp["indexer"], h)                # [Bc,C,Di]
-        ik_slot = ik_slot.at[bi, jnp.where(widx >= 0, widx, S)].set(
-            new_ik.astype(ik_slot.dtype), mode="drop")
-        ik_full = jax.lax.dynamic_update_slice_in_dim(ik_full, ik_slot, b0,
-                                                      axis=0)
+        with jax.named_scope("ess.indexer"):
+            ik_full = ikeys_all[layer]
+            ik_slot = jax.lax.dynamic_slice_in_dim(ik_full, b0, Bc, axis=0)
+            new_ik = M.indexer_keys(lp["indexer"], h)            # [Bc,C,Di]
+            ik_slot = ik_slot.at[bi, jnp.where(widx >= 0, widx, S)].set(
+                new_ik.astype(ik_slot.dtype), mode="drop")
+            ik_full = jax.lax.dynamic_update_slice_in_dim(ik_full, ik_slot,
+                                                          b0, axis=0)
         ikeys_all = ikeys_all[:layer] + (ik_full,) + ikeys_all[layer + 1:]
-        new_lat = M.latent_entries(lp["mla"], cfg, h, positions)  # [Bc,C,D]
-        if caches.host_scales is None:
-            new_lat = new_lat.astype(host.dtype)
-            lat_stack.append(new_lat)
-        else:
-            # quantize ONCE: the (q, s) pair is what the deferred stacked
-            # scatter commits, and intra-chunk attention serves
-            # dequant(q, s) — the same value any *cross*-chunk query
-            # reads back from the tier, so chunked == one-shot parity
-            # survives quantization
-            q_lat, s_lat = cmp.quantize_rows(new_lat, host.dtype)
-            lat_stack.append(q_lat)
-            scale_stack.append(s_lat)
-            new_lat = cmp.dequantize_rows(q_lat, s_lat, cfg.param_dtype)
+        with jax.named_scope("ess.attend"):
+            new_lat = M.latent_entries(lp["mla"], cfg, h, positions)
+        with jax.named_scope("ess.spill"):
+            if caches.host_scales is None:
+                new_lat = new_lat.astype(host.dtype)
+                lat_stack.append(new_lat)
+            else:
+                # quantize ONCE: the (q, s) pair is what the deferred
+                # stacked scatter commits, and intra-chunk attention
+                # serves dequant(q, s) — the same value any *cross*-chunk
+                # query reads back from the tier, so chunked == one-shot
+                # parity survives quantization
+                q_lat, s_lat = cmp.quantize_rows(new_lat, host.dtype)
+                lat_stack.append(q_lat)
+                scale_stack.append(s_lat)
+                new_lat = cmp.dequantize_rows(q_lat, s_lat, cfg.param_dtype)
 
         # --- exact causal DSA: per-query Top-K over the slot's keys ------
-        iq = M.indexer_query(lp["indexer"], h)
-        sc = M.indexer_scores(iq, ik_slot)                       # [Bc,C,S]
-        ids = M.topk_ids(sc, K, causal)                          # [Bc,C,K]
-        req_valid = jnp.take_along_axis(
-            jnp.broadcast_to(causal, (Bc, C, S)), ids, axis=2)
+        with jax.named_scope("ess.indexer"):
+            iq = M.indexer_query(lp["indexer"], h)
+            sc = M.indexer_scores(iq, ik_slot)                   # [Bc,C,S]
+        with jax.named_scope("ess.topk"):
+            ids = M.topk_ids(sc, K, causal)                      # [Bc,C,K]
+            req_valid = jnp.take_along_axis(
+                jnp.broadcast_to(causal, (Bc, C, S)), ids, axis=2)
         # prior context from host pages; intra-chunk rows from the chunk
-        local = ids >= start[:, None, None]
-        prior_ids = jnp.where(local, -1, ids)
-        rows_h = offload.gather_tier_rows(
-            host, caches.host_scales, prior_ids.reshape(Bc, C * K),
-            layer=layer, batch_offset=b0, block_table=caches.block_tables,
-            out_dtype=new_lat.dtype).reshape(Bc, C, K, -1)
-        loc = jnp.clip(ids - start[:, None, None], 0, C - 1)
-        rows_l = jnp.take_along_axis(new_lat[:, None], loc[..., None],
-                                     axis=2)                     # [Bc,C,K,D]
-        rows = jnp.where(local[..., None], rows_l, rows_h)
+        with jax.named_scope("ess.miss_gather"):
+            local = ids >= start[:, None, None]
+            prior_ids = jnp.where(local, -1, ids)
+            rows_h = offload.gather_tier_rows(
+                host, caches.host_scales, prior_ids.reshape(Bc, C * K),
+                layer=layer, batch_offset=b0,
+                block_table=caches.block_tables,
+                out_dtype=new_lat.dtype).reshape(Bc, C, K, -1)
+            loc = jnp.clip(ids - start[:, None, None], 0, C - 1)
+            rows_l = jnp.take_along_axis(new_lat[:, None], loc[..., None],
+                                         axis=2)                 # [Bc,C,K,D]
+            rows = jnp.where(local[..., None], rows_l, rows_h)
 
-        q_comb = M.absorbed_query(lp["mla"], cfg, h, positions)
-        # fp32 attend (prefill runs on the compute-rich side): matches the
-        # monolithic prefill/train references' softmax precision, so the
-        # selection sets of deeper layers don't drift across near-ties
-        part = _attend_rows(q_comb.astype(jnp.float32),
-                            rows.astype(jnp.float32), req_valid, cfg,
-                            use_kernel=use_kernel)
-        attn = M.output_proj(lp["mla"], cfg,
-                             M.finalize_partial(part, x.dtype))
-        x = x + attn
+        with jax.named_scope("ess.attend"):
+            q_comb = M.absorbed_query(lp["mla"], cfg, h, positions)
+            # fp32 attend (prefill runs on the compute-rich side): matches
+            # the monolithic prefill/train references' softmax precision,
+            # so the selection sets of deeper layers don't drift across
+            # near-ties
+            part = _attend_rows(q_comb.astype(jnp.float32),
+                                rows.astype(jnp.float32), req_valid, cfg,
+                                use_kernel=use_kernel)
+            attn = M.output_proj(lp["mla"], cfg,
+                                 M.finalize_partial(part, x.dtype))
+            x = x + attn
 
         # --- ffn ----------------------------------------------------------
-        h2 = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
-        if is_moe:
-            f, _ = MoE.moe_apply(lp["ffn"], cfg, h2)
-        else:
-            f = L.mlp(lp["ffn"], h2, cfg.act)
-        x = x + f
+        with jax.named_scope("ess.ffn"):
+            h2 = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
+            if is_moe:
+                f, _ = MoE.moe_apply(lp["ffn"], cfg, h2)
+            else:
+                f = L.mlp(lp["ffn"], h2, cfg.act)
+            x = x + f
 
     # one stacked D2H scatter for the whole chunk (all layers, same rows;
     # pad rows carry widx == -1 and are dropped).  Quantized tier: payload
     # and scale plane each take one stacked scatter of the precomputed
     # (q, s) pairs — compressed D2H width
-    host = offload.host_scatter_rows_stacked(
-        host, widx, jnp.stack(lat_stack), slot_mask=None, batch_offset=b0,
-        block_table=caches.block_tables)
-    host_scales = caches.host_scales
-    if host_scales is not None:
-        host_scales = offload.host_scatter_rows_stacked(
-            host_scales, widx, jnp.stack(scale_stack), slot_mask=None,
+    with jax.named_scope("ess.spill"):
+        host = offload.host_scatter_rows_stacked(
+            host, widx, jnp.stack(lat_stack), slot_mask=None,
             batch_offset=b0, block_table=caches.block_tables)
-    new_lens = jax.lax.dynamic_update_slice(
-        caches.lens, start + nv, (b0,))
+        host_scales = caches.host_scales
+        if host_scales is not None:
+            host_scales = offload.host_scatter_rows_stacked(
+                host_scales, widx, jnp.stack(scale_stack), slot_mask=None,
+                batch_offset=b0, block_table=caches.block_tables)
+        new_lens = jax.lax.dynamic_update_slice(
+            caches.lens, start + nv, (b0,))
     logits = None
     hidden_last = None
     if want_logits:
-        xf = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = L.unembed(params.get("unembed", params.get("embed")), xf,
-                           cap=cfg.logit_softcap)
-        hidden_last = xf[:, jnp.maximum(nv - 1, 0)]          # [Bc, d]
+        with jax.named_scope("ess.head"):
+            xf = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            logits = L.unembed(params.get("unembed", params.get("embed")),
+                               xf, cap=cfg.logit_softcap)
+            hidden_last = xf[:, jnp.maximum(nv - 1, 0)]          # [Bc, d]
     caches = caches._replace(lens=new_lens, host_latent=host,
                              host_scales=host_scales, ikeys=ikeys_all)
     return logits, caches, tuple(tails), hidden_last
@@ -1065,37 +1112,38 @@ class ServeSession:
             return False
         slot = next(iter(self._prefill))         # FIFO by insertion order
         task = self._prefill[slot]
-        n = task.req.prompt_len
-        c0 = task.cursor
-        ck = min(self.prefill_chunk, n - c0)
-        last = c0 + ck >= n
-        if self.do_warmup:
-            t0 = self._prefill_chunk_warmup(slot, task, c0, ck, n, last)
-            t0_dev = None
-        else:
-            C = SP.chunk_bucket(ck, self.prefill_chunk)
-            toks = task.tokens[:, c0:c0 + ck]
-            if C > ck:
-                toks = jnp.pad(toks, ((0, 0), (0, C - ck)))
-            fn = self._programs.prefill(C, last, self.compiled)
-            self.state, t0_dev = fn(self.params, self.state, toks,
-                                    jnp.asarray(slot, jnp.int32),
-                                    jnp.asarray(ck, jnp.int32))
-        task.cursor += ck
-        self.report.prefill_chunks += 1
-        self.report.prefill_tokens += ck
-        self.report.events.append(
-            f"round {self._round}: rid={task.req.rid} prefill chunk "
-            f"[{c0}:{c0 + ck})/{n} (slot {slot})")
-        if last:
+        with TraceAnnotation("ess.prefill", rid=task.req.rid):
+            n = task.req.prompt_len
+            c0 = task.cursor
+            ck = min(self.prefill_chunk, n - c0)
+            last = c0 + ck >= n
             if self.do_warmup:
-                self._finish_prefill(slot, task, t0)
+                t0 = self._prefill_chunk_warmup(slot, task, c0, ck, n, last)
+                t0_dev = None
             else:
-                req = task.req
-                self.sched.promote(slot)
-                self._rounds_since_promote[slot] = 0
-                del self._prefill[slot]
-                self._pending_first.append((slot, req, t0_dev))
+                C = SP.chunk_bucket(ck, self.prefill_chunk)
+                toks = task.tokens[:, c0:c0 + ck]
+                if C > ck:
+                    toks = jnp.pad(toks, ((0, 0), (0, C - ck)))
+                fn = self._programs.prefill(C, last, self.compiled)
+                self.state, t0_dev = fn(self.params, self.state, toks,
+                                        jnp.asarray(slot, jnp.int32),
+                                        jnp.asarray(ck, jnp.int32))
+            task.cursor += ck
+            self.report.prefill_chunks += 1
+            self.report.prefill_tokens += ck
+            self.report.events.append(
+                f"round {self._round}: rid={task.req.rid} prefill chunk "
+                f"[{c0}:{c0 + ck})/{n} (slot {slot})")
+            if last:
+                if self.do_warmup:
+                    self._finish_prefill(slot, task, t0)
+                else:
+                    req = task.req
+                    self.sched.promote(slot)
+                    self._rounds_since_promote[slot] = 0
+                    del self._prefill[slot]
+                    self._pending_first.append((slot, req, t0_dev))
         return True
 
     def _prefill_chunk_warmup(self, slot: int, task: _PrefillTask, c0: int,
@@ -1324,13 +1372,25 @@ class ServeSession:
         ``device_get``), then scheduler bookkeeping + stream emission.
         Every TokenEvent is stamped with the post-fetch *delivery*
         instant, not the time this bookkeeping finishes."""
-        active, pending, spec = plan.active, plan.pending, plan.spec
         pf = () if out.pf_hits is None else \
             (out.pf_hits, out.pf_misses, out.pf_wasted)
         h2d = () if out.h2d_rows is None else (out.h2d_rows,)
-        toks, n_emit, t0s, pf_host, h2d_host = jax.device_get(
-            (out.tokens, out.n_emit, [t for _, _, t in pending], pf, h2d))
+        t0_devs = [t for _, _, t in plan.pending]
+        with TraceAnnotation("ess.fetch"):
+            toks, n_emit, t0s, pf_host, h2d_host = jax.device_get(
+                (out.tokens, out.n_emit, t0_devs, pf, h2d))
         t_deliver = time.perf_counter()
+        with TraceAnnotation("ess.commit"):
+            return self._commit_tokens(plan, toks, n_emit, t0s, pf_host,
+                                       h2d_host, t_deliver)
+
+    def _commit_tokens(self, plan: "_RoundPlan", toks, n_emit, t0s,
+                       pf_host, h2d_host, t_deliver: float
+                       ) -> list[Request]:
+        """Commit-stage bookkeeping over the fetched host values: counters,
+        first-token and token delivery, stop-token rollback, scheduler
+        accounting."""
+        active, pending, spec = plan.active, plan.pending, plan.spec
         if pf_host:
             self.transfer.commit(self.report, pf_host[0].sum(),
                                  pf_host[1].sum(), pf_host[2].sum())
@@ -1411,10 +1471,12 @@ class ServeSession:
         donated device state; inactive and mid-prefill slots are masked
         *inside* the step (``slot_mask``).  The host fetches exactly one
         packed struct per round in the commit stage."""
-        plan = self._plan_round()
+        with TraceAnnotation("ess.plan"):
+            plan = self._plan_round()
         if plan is None:
             return []
-        out = self._compute_round(plan)
+        with TraceAnnotation("ess.launch"):
+            out = self._compute_round(plan)
         return self._commit_round(plan, out)
 
     def _handle_done(self, done: list[Request]) -> None:
@@ -1438,10 +1500,13 @@ class ServeSession:
         Wall time accumulates per round, so throughput metrics hold for
         any driver (``run``, ``generate``, manual ``step`` loops)."""
         t0 = time.perf_counter()
-        self.admit()
-        self.prefill_round()
-        done = self.decode_round()
-        self._handle_done(done)
+        with StepTraceAnnotation("ess.round", step_num=self._round):
+            with TraceAnnotation("ess.admit"):
+                self.admit()
+            self.prefill_round()
+            done = self.decode_round()
+            with TraceAnnotation("ess.finish"):
+                self._handle_done(done)
         self._round += 1
         self._last_done = done
         self.report.wall_s += time.perf_counter() - t0

@@ -40,7 +40,11 @@ debugging path: per-round logits, caches and emission packing are all
 visible at unit boundaries.
 
 Programs are cached process-wide (``get_programs``) so every session
-with the same ``(cfg, shape family)`` reuses the same executables.
+with the same ``(cfg, shape family)`` reuses the same executables.  Each
+round function is named for its kind (``decode_round``, ``spec_round``,
+``prefill_chunk``), which makes its XLA module name in a profiler trace
+(``jit_decode_round``, ...); its stages run under ``ess.*`` named scopes
+(README "Tracing").
 
 ``TRACE_COUNTS[key]`` increments inside each round-function body, i.e.
 at *trace* time under jit — the recompile-count guard test asserts every
@@ -141,38 +145,43 @@ def _decode_round_fn(units: _Units, key: str) -> Callable:
     """Plain Q=1 round: step the live batch, select each slot's next
     token (greedy or sampled from the per-slot knob arrays)."""
 
-    def fn(params, state: EngineState):
+    def decode_round(params, state: EngineState):
         TRACE_COUNTS[key] += 1
         caches = state.caches
         staged = None if state.staged_ids is None else \
             (state.staged_ids, state.staged_rows, state.staged_scales)
-        out = units.step(params, state.tok[:, None], caches.lens[:, None],
-                         caches, state.slot_mask, staged)
-        logits = out.logits[:, -1]                             # [B,V]
-        g = greedy(logits)
-        smp = _maybe_sample(units, state, logits, g)
-        t = jnp.where(state.sample_mask, smp, g)
-        live = state.slot_mask
-        upd = {} if staged is None else dict(
-            staged_ids=out.stats["staged_ids"],
-            staged_rows=out.stats["staged_rows"],
-            staged_scales=out.stats.get("staged_scales"))
-        new_state = state._replace(
-            caches=out.caches,
-            tok=jnp.where(live, t, state.tok),
-            hidden=jnp.where(live[:, None], out.stats["hidden"][:, -1],
-                             state.hidden),
-            emit_index=state.emit_index + live.astype(jnp.int32),
-            **upd)
-        ro = RoundOut(jnp.where(live, t, 0)[:, None], live.astype(jnp.int32),
-                      h2d_rows=out.stats["misses"].sum())
+        with jax.named_scope("ess.embed"):
+            tokens, positions = state.tok[:, None], caches.lens[:, None]
+        out = units.step(params, tokens, positions, caches, state.slot_mask,
+                         staged)
+        # token selection and emission packing
+        with jax.named_scope("ess.head"):
+            logits = out.logits[:, -1]                         # [B,V]
+            g = greedy(logits)
+            smp = _maybe_sample(units, state, logits, g)
+            t = jnp.where(state.sample_mask, smp, g)
+            live = state.slot_mask
+            upd = {} if staged is None else dict(
+                staged_ids=out.stats["staged_ids"],
+                staged_rows=out.stats["staged_rows"],
+                staged_scales=out.stats.get("staged_scales"))
+            new_state = state._replace(
+                caches=out.caches,
+                tok=jnp.where(live, t, state.tok),
+                hidden=jnp.where(live[:, None], out.stats["hidden"][:, -1],
+                                 state.hidden),
+                emit_index=state.emit_index + live.astype(jnp.int32),
+                **upd)
+            ro = RoundOut(jnp.where(live, t, 0)[:, None],
+                          live.astype(jnp.int32),
+                          h2d_rows=out.stats["misses"].sum())
         if staged is not None:
             ro = ro._replace(pf_hits=out.stats["pf_hits"],
                              pf_misses=out.stats["pf_misses"],
                              pf_wasted=out.stats["pf_wasted"])
         return new_state, ro
 
-    return fn
+    return decode_round
 
 
 def _spec_round_fn(units: _Units, key: str) -> Callable:
@@ -183,43 +192,43 @@ def _spec_round_fn(units: _Units, key: str) -> Callable:
     position-0 logits (the exact Q=1 distribution) with the same
     ``(seed, emit_index)`` key the Q=1 program would fold."""
 
-    def fn(params, state: EngineState):
+    def spec_round(params, state: EngineState):
         TRACE_COUNTS[key] += 1
         live = state.slot_mask
         staged = None if state.staged_ids is None else \
             (state.staged_ids, state.staged_rows, state.staged_scales)
         spec = units.spec(params, state.caches, state.tok, state.hidden,
                           live, state.sample_mask, staged)
-        # false branch reuses the verify step's own position-0 argmax
-        smp = _maybe_sample(units, state, spec.logits[:, 0],
-                            spec.tokens[:, 0])
-        tokens = spec.tokens.at[:, 0].set(
-            jnp.where(state.sample_mask, smp, spec.tokens[:, 0]))
-        n_emit = jnp.where(live,
-                           jnp.where(state.sample_mask, 1, spec.n_accepted),
-                           0)
-        last = jnp.take_along_axis(tokens,
-                                   jnp.maximum(n_emit - 1, 0)[:, None],
-                                   axis=1)[:, 0]
-        upd = {} if staged is None else dict(
-            staged_ids=spec.stats["staged_ids"],
-            staged_rows=spec.stats["staged_rows"],
-            staged_scales=spec.stats.get("staged_scales"))
-        new_state = state._replace(
-            caches=spec.caches,
-            tok=jnp.where(live, last, state.tok),
-            hidden=jnp.where(live[:, None], spec.hidden, state.hidden),
-            emit_index=state.emit_index + live.astype(jnp.int32),
-            **upd)
-        ro = RoundOut(jnp.where(live[:, None], tokens, 0), n_emit,
-                      h2d_rows=spec.stats["misses"].sum())
+        with jax.named_scope("ess.head"):
+            # false branch reuses the verify step's own position-0 argmax
+            smp = _maybe_sample(units, state, spec.logits[:, 0],
+                                spec.tokens[:, 0])
+            tokens = spec.tokens.at[:, 0].set(
+                jnp.where(state.sample_mask, smp, spec.tokens[:, 0]))
+            n_emit = jnp.where(
+                live, jnp.where(state.sample_mask, 1, spec.n_accepted), 0)
+            last = jnp.take_along_axis(tokens,
+                                       jnp.maximum(n_emit - 1, 0)[:, None],
+                                       axis=1)[:, 0]
+            upd = {} if staged is None else dict(
+                staged_ids=spec.stats["staged_ids"],
+                staged_rows=spec.stats["staged_rows"],
+                staged_scales=spec.stats.get("staged_scales"))
+            new_state = state._replace(
+                caches=spec.caches,
+                tok=jnp.where(live, last, state.tok),
+                hidden=jnp.where(live[:, None], spec.hidden, state.hidden),
+                emit_index=state.emit_index + live.astype(jnp.int32),
+                **upd)
+            ro = RoundOut(jnp.where(live[:, None], tokens, 0), n_emit,
+                          h2d_rows=spec.stats["misses"].sum())
         if staged is not None:
             ro = ro._replace(pf_hits=spec.stats["pf_hits"],
                              pf_misses=spec.stats["pf_misses"],
                              pf_wasted=spec.stats["pf_wasted"])
         return new_state, ro
 
-    return fn
+    return spec_round
 
 
 def _prefill_round_fn(chunk_core: Callable, units: _Units, last: bool,
@@ -230,7 +239,7 @@ def _prefill_round_fn(chunk_core: Callable, units: _Units, last: bool,
     round: ``tok``/``hidden``/``emit_index``/``slot_mask`` flip so the
     host only fetches the one first-token scalar."""
 
-    def fn(params, state: EngineState, tokens, slot, n_valid):
+    def prefill_chunk(params, state: EngineState, tokens, slot, n_valid):
         TRACE_COUNTS[key] += 1
         if not last:
             caches = chunk_core(params, state.caches, tokens, slot, n_valid)
@@ -238,16 +247,18 @@ def _prefill_round_fn(chunk_core: Callable, units: _Units, last: bool,
         lg, caches, hid_last = chunk_core(params, state.caches, tokens,
                                           slot, n_valid)
         state = state._replace(caches=caches)
-        lg_last = lg[0, jnp.maximum(n_valid - 1, 0)]                 # [V]
-        g = greedy(lg_last)
-        smp = units.sample_one(state.seed[slot], state.emit_index[slot],
-                               lg_last, state.temperature[slot],
-                               state.top_k[slot], state.top_p[slot])
-        t0 = jnp.where(state.sample_mask[slot], smp, g)
-        state = promote_slot(state, slot, t0, hid_last[0])
+        # first-token selection and the slot's promotion
+        with jax.named_scope("ess.head"):
+            lg_last = lg[0, jnp.maximum(n_valid - 1, 0)]             # [V]
+            g = greedy(lg_last)
+            smp = units.sample_one(state.seed[slot], state.emit_index[slot],
+                                   lg_last, state.temperature[slot],
+                                   state.top_k[slot], state.top_p[slot])
+            t0 = jnp.where(state.sample_mask[slot], smp, g)
+            state = promote_slot(state, slot, t0, hid_last[0])
         return state, t0
 
-    return fn
+    return prefill_chunk
 
 
 def _make_chunk_core(cfg: ArchConfig, use_kernel: bool,
@@ -256,8 +267,9 @@ def _make_chunk_core(cfg: ArchConfig, use_kernel: bool,
 
     def core(params, caches, tokens, slot, n_valid):
         C = tokens.shape[1]
-        start = jax.lax.dynamic_slice_in_dim(caches.lens, slot, 1)   # [1]
-        positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+        with jax.named_scope("ess.embed"):
+            start = jax.lax.dynamic_slice_in_dim(caches.lens, slot, 1)  # [1]
+            positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         lg, caches, _, hid_last = E.ess_prefill_chunk(
             params, cfg, tokens, positions, caches, slot=slot,
             want_logits=last, collect_tail=0, use_kernel=use_kernel,
