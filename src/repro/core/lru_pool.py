@@ -14,10 +14,10 @@ State (leading batch dim B everywhere):
 * ``step     []``         monotone step counter
 
 Fixed-shape miss handling: each step fetches at most ``M`` rows (the
-provisioned H2D envelope).  ``lax.top_k`` returns ids in descending indexer
-score order, so when misses overflow M the *lowest-scoring* entries are the
-ones dropped (masked out of attention, softmax renormalizes exactly over
-the attended set).  ``stats.overflow`` counts them; sizing M per the paper's
+provisioned H2D envelope).  ``models.mla.topk_ids`` returns ids in descending
+indexer score order (``lax.top_k``'s), so when misses overflow M the
+*lowest-scoring* entries are the ones dropped (masked out of attention,
+softmax renormalizes exactly over the attended set).  ``stats.overflow`` counts them; sizing M per the paper's
 miss profiles (16–605/batch at ratio 0.2) makes overflow rare.
 
 Jit contract: every state transition here (``lookup`` / ``admit`` /

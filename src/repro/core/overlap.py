@@ -136,11 +136,9 @@ def _topk_and_lookup(idx_p, cfg, x_norm, state, idx_keys, lens, slot_mask):
         iq = M.indexer_query(idx_p, x_norm)
         sc = M.indexer_scores(iq, idx_keys)                      # [B,Q,S]
     with jax.named_scope("ess.topk"):
-        qlens = lens[:, None] if lens.ndim == 1 else lens        # [B,Q]
-        valid_s = jnp.arange(S)[None, None, :] < qlens[:, :, None]
-        valid_s = jnp.broadcast_to(valid_s, (B, Q, S))           # [B,Q,S]
-        ids = M.topk_ids(sc, K, valid_s)                         # [B,Q,K]
-        req_valid = jnp.take_along_axis(valid_s, ids, axis=2)
+        qlens = (lens[:, None] if lens.ndim == 1 else lens)[..., None]
+        ids = M.topk_ids(sc, K, jnp.arange(S) < qlens)          # [B,Q,K]
+        req_valid = ids < qlens                                  # prefix mask
     with jax.named_scope("ess.pool"):
         flat_ids = ids.reshape(B, Q * K)
         flat_valid = req_valid.reshape(B, Q * K)

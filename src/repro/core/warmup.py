@@ -44,10 +44,8 @@ def lru_warmup(pool: LP.PoolState, host_latent: jax.Array,
 
     iq = M.indexer_query(idx_p, x_tail)                  # queries for W windows
     sc = M.indexer_scores(iq, idx_keys)                  # [B,W,S]
-    valid_s = jnp.arange(S)[None, :] < lens[:, None]
-    ids_w = M.topk_ids(sc, K, valid_s[:, None])          # [B,W,K]
-    valid_w = jnp.take_along_axis(
-        jnp.broadcast_to(valid_s[:, None], (B, W, S)), ids_w, axis=2)
+    ids_w = M.topk_ids(sc, K, jnp.arange(S) < lens[:, None, None])  # [B,W,K]
+    valid_w = ids_w < lens[:, None, None]                # prefix mask
 
     def body(p, wi):
         ids, vw = wi                                     # [B,K]

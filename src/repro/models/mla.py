@@ -139,11 +139,106 @@ def indexer_scores(iq: IndexerQuery, keys: jax.Array) -> jax.Array:
 
 def topk_ids(scores: jax.Array, k: int, valid_mask: jax.Array | None = None
              ) -> jax.Array:
-    """Top-k cache indices per query row. scores [B,Q,S] -> ids [B,Q,k]."""
+    """Top-k cache indices per query row. scores [..., S] -> ids [..., k].
+
+    Bit-identical to ``lax.top_k(where(valid_mask, scores, NEG_INF), k)[1]``
+    — the same ids in the same order (score descending, the lower
+    position first among equal scores, -0.0 below +0.0) — without sorting
+    the S scores, which on the TPU is a full sort of every row: the k-th
+    largest key is found by counting passes, the k kept positions are
+    compacted in position order, and only those k are sorted."""
     if valid_mask is not None:
         scores = jnp.where(valid_mask, scores, NEG_INF)
-    _, ids = jax.lax.top_k(scores, k)
-    return ids
+    *lead, S = scores.shape
+    u = _order_key(scores.reshape(-1, S).astype(jnp.float32))   # [R,S]
+    ids, keys = _compact(_keep_mask(u, k), _in_blocks(u), k)
+    _, ids = jax.lax.sort((~keys, ids), num_keys=2)
+    return ids.reshape(*lead, k)
+
+
+_BLOCK = 128        # positions per block of the running counts
+
+
+def _order_key(x: jax.Array) -> jax.Array:
+    """uint32 image of f32 ``x`` in ``lax.top_k``'s order (IEEE total
+    order: -0.0 below +0.0): negatives bit-inverted, the sign bit set on
+    the rest."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where((b >> 31) == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def _in_blocks(x: jax.Array) -> jax.Array:
+    """[R,S] -> [R,blocks,BLOCK], padded with zeros (False)."""
+    R, S = x.shape
+    nb = -(-S // _BLOCK)
+    return jnp.pad(x, ((0, 0), (0, nb * _BLOCK - S))).reshape(R, nb, _BLOCK)
+
+
+def _block_count(mask: jax.Array) -> jax.Array:
+    """Inclusive running count of ``mask`` [R,blocks,BLOCK] within each
+    block: a matmul with a triangle of ones (0/1 operands, sums of at most
+    BLOCK: exact), where a cumsum along the whole row is slow on the
+    TPU."""
+    e = jnp.arange(_BLOCK)
+    tri = (e[:, None] <= e[None, :]).astype(jnp.bfloat16)
+    return jnp.einsum("rbi,ij->rbj", mask.astype(jnp.bfloat16), tri,
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _keep_mask(u: jax.Array, k: int) -> jax.Array:
+    """[R,blocks,BLOCK] bool: exactly k keys of each row of ``u`` [R,S] —
+    every key above the row's k-th largest, then the first of those equal
+    to it by position.  The k-th largest is settled one bit a counting
+    pass, from the top bit down."""
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = (u >= cand[:, None]).sum(-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, bit, jnp.zeros(u.shape[:1], jnp.uint32))
+    gt = u > thr[:, None]
+    eq = _in_blocks(u == thr[:, None])
+    run = _block_count(eq)
+    cnt = run[..., -1]
+    run = run + (jnp.cumsum(cnt, -1) - cnt)[..., None]     # along the row
+    n_tie = k - gt.sum(-1, dtype=jnp.int32)
+    return _in_blocks(gt) | (eq & (run <= n_tie[:, None, None]))
+
+
+def _compact(keep: jax.Array, u: jax.Array, k: int
+             ) -> tuple[jax.Array, jax.Array]:
+    """The k kept positions of each row of ``keep`` [R,blocks,BLOCK], in
+    position order, with their keys from ``u`` (same shape):
+    ``(ids [R,k] int32, keys [R,k] uint32)``.
+
+    A gather on the TPU goes element by element, so this one is a matmul:
+    kept j lies in the block whose kept count first passes j, and a
+    one-hot [k, blocks] matrix brings that block's running kept count and
+    the four bytes of its keys (integers below 256, exact in bf16) to row
+    j, where the running count locates j within the block."""
+    R, nb, _ = keep.shape
+    run = _block_count(keep)
+    cnt = run[..., -1]
+    end = jnp.cumsum(cnt, -1)[:, None, :]                   # [R,1,nb]
+    start = end - cnt[:, None, :]
+    j = jnp.arange(k, dtype=jnp.int32)[None, :, None]
+    hot = (start <= j) & (j < end)                          # [R,k,nb]
+    blk = jnp.where(hot, jnp.arange(nb, dtype=jnp.int32), 0).sum(-1)
+    rank = j[..., 0] - jnp.where(hot, start, 0).sum(-1)     # within blk
+    planes = [run] + [((u >> (8 * i)) & 255).astype(jnp.int32)
+                      for i in range(4)]
+    g = jnp.einsum("rkb,rbe->rke", hot.astype(jnp.bfloat16),
+                   jnp.concatenate(planes, -1).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.bfloat16)     # [R,k,5*BLOCK]
+    pos = (g[..., :_BLOCK] <= rank[..., None].astype(g.dtype)).sum(
+        -1, dtype=jnp.int32)
+    at = jnp.arange(_BLOCK) == pos[..., None]
+    keys = jnp.zeros((R, k), jnp.uint32)
+    for i in range(4):
+        byte = jnp.where(at, g[..., (i + 1) * _BLOCK:(i + 2) * _BLOCK], 0)
+        keys = keys | (byte.sum(-1, dtype=jnp.float32).astype(jnp.uint32)
+                       << (8 * i))
+    return blk * _BLOCK + pos, keys
 
 
 # ---------------------------------------------------------------------------
@@ -216,7 +311,7 @@ def sparse_mla_decode(p: dict, pi: dict, cfg: ArchConfig, x: jax.Array,
     else:
         B, Q, K = ids.shape
         gl = jnp.take_along_axis(latent_cache[:, None], ids[..., None], axis=2)
-        gv = jnp.take_along_axis(valid[:, None], ids, axis=2)    # [B,Q,K]
+        gv = ids < cache_len[:, None, None]                      # [B,Q,K]
         s = jnp.einsum("bqhd,bqkd->bqhk", q_comb.astype(jnp.float32),
                        gl.astype(jnp.float32)) * mla_scale(cfg)
         s = jnp.where(gv[:, :, None, :], s, NEG_INF)

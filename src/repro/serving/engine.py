@@ -478,8 +478,7 @@ def ess_prefill_chunk(params, cfg: ArchConfig, tokens, positions,
             sc = M.indexer_scores(iq, ik_slot)                   # [Bc,C,S]
         with jax.named_scope("ess.topk"):
             ids = M.topk_ids(sc, K, causal)                      # [Bc,C,K]
-            req_valid = jnp.take_along_axis(
-                jnp.broadcast_to(causal, (Bc, C, S)), ids, axis=2)
+            req_valid = ids <= widx[:, :, None]                  # prefix mask
         # prior context from host pages; intra-chunk rows from the chunk
         with jax.named_scope("ess.miss_gather"):
             local = ids >= start[:, None, None]
