@@ -1,12 +1,14 @@
 """From a configuration file of ``bench/configs`` to the program's config.
 
-``KEY_MAP`` is the one table: each key of the published config names the
-field of the program's config dataclasses it sets (``moe.top_k`` is field
-``top_k`` of ``cfg.moe``).  A key whose field the program's dataclasses
-lack today is reported as not taken; once a later change adds the field
-under that name, the key is applied with no edit here.  A width must
-equal the field it maps onto, or the run fails.  Keys absent from the
-table are reported as not taken too.
+Each key of the published config names the field of the program's config
+dataclasses it sets (``moe.top_k`` is field ``top_k`` of ``cfg.moe``).
+``COMMON`` holds the keys every catalog entry shares; the architecture
+module of the file's ``model_type`` (:func:`arch_module`) adds its own in
+its ``KEY_MAP``, and its entry wins where both name a key.  A key whose field
+the program's dataclasses lack today is reported as not taken; once a
+later change adds the field under that name, the key is applied with no
+edit here.  A width must equal the field it maps onto, or the run fails.
+Keys absent from both tables are reported as not taken too.
 
 ``serve`` holds the engine settings that are not model keys: the
 program's registered architecture (``arch``), the ESS options
@@ -16,50 +18,61 @@ program's registered architecture (``arch``), the ESS options
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
+import os
 from typing import Any
 
+# the architecture modules, one per model_type: bench/archs/<model_type>.py
+ARCHS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "archs")
+
 # published key -> (field path in the program's ArchConfig, is a width)
-KEY_MAP: dict[str, tuple[str, bool]] = {
+COMMON: dict[str, tuple[str, bool]] = {
     "hidden_size": ("d_model", True),
     "num_attention_heads": ("num_heads", True),
     "num_key_value_heads": ("num_kv_heads", True),
-    "intermediate_size": ("moe.dense_d_ff", True),
-    "moe_intermediate_size": ("moe.d_expert", True),
-    "q_lora_rank": ("mla.q_lora_rank", True),
-    "kv_lora_rank": ("mla.kv_lora_rank", True),
-    "qk_nope_head_dim": ("mla.qk_nope_head_dim", True),
-    "qk_rope_head_dim": ("mla.qk_rope_head_dim", True),
-    "v_head_dim": ("mla.v_head_dim", True),
-    "index_n_heads": ("dsa.index_heads", True),
-    "index_head_dim": ("dsa.index_dim", True),
-    "num_experts_per_tok": ("moe.top_k", True),
     "vocab_size": ("vocab_size", False),
-    "index_topk": ("dsa.index_topk", False),
     "num_hidden_layers": ("num_layers", False),
-    "n_routed_experts": ("moe.num_experts", False),
-    "n_shared_experts": ("moe.num_shared", False),
-    "first_k_dense_replace": ("moe.first_dense_layers", False),
-    "routed_scaling_factor": ("moe.routed_scale", False),
-    "norm_topk_prob": ("moe.norm_topk", False),
     "rms_norm_eps": ("norm_eps", False),
     "rope_theta": ("rope_theta", False),
-    "num_nextn_predict_layers": ("mtp_depth", False),
     "tie_word_embeddings": ("tie_embeddings", False),
     "attention_bias": ("qkv_bias", False),
     "hidden_act": ("act", False),
     "max_position_embeddings": ("max_position_embeddings", False),
-    "n_group": ("moe.n_group", False),
-    "topk_group": ("moe.topk_group", False),
-    "topk_method": ("moe.topk_method", False),
-    "scoring_func": ("moe.scoring_func", False),
-    "moe_layer_freq": ("moe.layer_freq", False),
-    "ep_size": ("moe.ep_size", False),
     "rope_scaling": ("rope_scaling", False),
 }
 # keys of the file that describe it rather than the model
 META = ("source", "published", "deployment", "departures", "serve",
         "assumed", "model_type")
+
+
+@functools.lru_cache(maxsize=None)
+def _load_arch(path: str):
+    name = "bench_arch_" + os.path.basename(path)[:-3]
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def arch_module(spec: dict):
+    """The module ``<ARCHS>/<model_type>.py`` of a configuration file: its
+    ``KEY_MAP``, its ``Reference`` (``bench/reference.py``) and its
+    ``Model`` (``bench/flops.py``)."""
+    if "model_type" not in spec:
+        raise KeyError("the configuration file has no model_type")
+    path = os.path.join(ARCHS, spec["model_type"] + ".py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"model_type {spec['model_type']!r}: no "
+                                f"architecture module {path}")
+    return _load_arch(path)
+
+
+def key_map(spec: dict) -> dict[str, tuple[str, bool]]:
+    """Published key -> (field path, is a width) for ``spec``'s
+    architecture."""
+    return {**COMMON, **arch_module(spec).KEY_MAP}
 
 
 @dataclasses.dataclass
@@ -108,14 +121,15 @@ def to_program(spec: dict) -> Mapped:
     from repro.configs import get_config
     serve = spec["serve"]
     cfg = get_config(serve["arch"])
+    table = key_map(spec)
     not_taken = []
     for key, value in spec.items():
         if key in META:
             continue
-        if key not in KEY_MAP:
+        if key not in table:
             not_taken.append(key)
             continue
-        path, width = KEY_MAP[key]
+        path, width = table[key]
         new = _set(cfg, path, value, width, key)
         if new is None:
             not_taken.append(key)

@@ -41,7 +41,8 @@ import tempfile          # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-ROW_BYTES = {"bf16": 1152, "int8": 578, "fp8": 578}
+# host tier dtype -> (bytes per latent element, bytes of the row's scale)
+TIER_BYTES = {"bf16": (2, 0), "int8": (1, 2), "fp8": (1, 2)}
 
 
 def log(msg: str) -> None:
@@ -52,21 +53,20 @@ def log(msg: str) -> None:
 # what a run measured
 # ---------------------------------------------------------------------------
 
-@dataclasses.dataclass
-class Counters:
-    """Serve counters at one instant (``ServeReport`` fields plus the
-    harness's own round count)."""
-    steps: int = 0
-    rounds: int = 0
-    prefill_chunks: int = 0
-    prefill_tokens: int = 0
-    decode_tokens: int = 0
-    h2d_rows: int = 0
-    n_events: int = 0
+class Counters(dict):
+    """Serve counters at one instant, read as attributes: every integer
+    field of ``ServeReport`` (``rounds``, ``h2d_rows``, ...), the harness's
+    own round count ``steps`` and the number of report events
+    ``n_events``."""
+
+    def __getattr__(self, name: str) -> int:
+        try:
+            return self[name]
+        except KeyError:
+            raise AttributeError(name) from None
 
     def minus(self, o: "Counters") -> "Counters":
-        return Counters(*(a - b for a, b in zip(dataclasses.astuple(self),
-                                                dataclasses.astuple(o))))
+        return Counters({k: v - o.get(k, 0) for k, v in self.items()})
 
 
 @dataclasses.dataclass
@@ -83,16 +83,24 @@ class Window:
     token_times: dict = dataclasses.field(default_factory=dict)
     prompt_len: dict = dataclasses.field(default_factory=dict)
     tokens_in_window: int = 0
-    start: Counters = dataclasses.field(default_factory=Counters)
-    end: Counters = dataclasses.field(default_factory=Counters)
+    start: Counters = dataclasses.field(default_factory=lambda: Counters(
+        steps=0))
+    end: Counters = dataclasses.field(default_factory=lambda: Counters(
+        steps=0))
     # traced span (``--trace 1``)
     traced: bool = False
     busy_s: float = 0.0
     window_s: float = 0.0
-    trace_counts: Counters = dataclasses.field(default_factory=Counters)
+    trace_counts: Counters = dataclasses.field(
+        default_factory=lambda: Counters(steps=0))
     trace_lens: list = dataclasses.field(default_factory=list)
     trace_decode_ctx: list = dataclasses.field(default_factory=list)
     breakdown: dict = dataclasses.field(default_factory=dict)
+    # device ms of each ess.* scope of the decode program per serve round
+    # of the traced span, and the host's own ms a round (bench/scopes.py)
+    scope_ms: dict = dataclasses.field(default_factory=dict)
+    round_host_ms: float | None = None
+    stages: dict = dataclasses.field(default_factory=dict)  # scopes.reduce
     peaks: dict = dataclasses.field(default_factory=dict)
     row_bytes: int = 0
 
@@ -150,8 +158,56 @@ class Recorder:
 
 def counters(eng, steps: int) -> Counters:
     r = eng.session.report
-    return Counters(steps, r.rounds, r.prefill_chunks, r.prefill_tokens,
-                    r.decode_tokens, r.h2d_rows, len(r.events))
+    c = Counters({f.name: getattr(r, f.name) for f in dataclasses.fields(r)
+                  if type(getattr(r, f.name)) is int})
+    c.update(steps=steps, n_events=len(r.events))
+    return c
+
+
+def row_bytes(spec: dict, dtype: str) -> int:
+    """Bytes of one latent row in the host tier: ``kv_lora_rank +
+    qk_rope_head_dim`` elements, and a row scale on a quantized tier."""
+    elem, scale = TIER_BYTES[dtype]
+    return elem * (spec["kv_lora_rank"] + spec["qk_rope_head_dim"]) + scale
+
+
+def stage_maps(eng) -> dict[str, dict[str, str]]:
+    """``{module: {instruction: ess.* scope}}`` of the round program the
+    engine decodes with, from its optimized HLO (lowered and compiled
+    again on the same arguments: a compile-cache hit)."""
+    from repro.analysis.hlo_scopes import op_scopes
+    s = eng.session
+    fn = s._programs.spec(True) if s.mtp_depth > 0 \
+        else s._programs.decode(True)
+    t = time.perf_counter()
+    mod, table = op_scopes(fn.lower(s.params, s.state).compile().as_text())
+    log(f"scopes: {mod} mapped ({len(table)} instructions) in "
+        f"{time.perf_counter() - t:.2f}s")
+    return {mod: table}
+
+
+def read_trace(w: Window, path: str, maps: dict) -> None:
+    """Put the device numbers of the trace at ``path`` on ``w``: busy and
+    window seconds, the top ops and idle gaps, and, on one device, each
+    scope's ms a serve round and the host's own ms a round."""
+    import scopes
+    import trace_reduce as tr
+    red = tr.reduce_events(*tr.load(path))
+    w.busy_s, w.window_s = red.busy_s, red.window_s
+    w.breakdown = {"device_ops": red.device_ops, "idle_gaps": red.idle_gaps}
+    if red.n_devices != 1:
+        log(f"scopes: not read on {red.n_devices} devices")
+        return
+    module = next(iter(maps), scopes.DECODE_MODULE)
+    w.stages = scopes.reduce(scopes.load(path), maps, module)
+    n = w.trace_counts.steps
+    if n:
+        w.scope_ms = {k: v / n * 1e3
+                      for k, v in w.stages["device"]["scopes"].items()}
+        w.breakdown["scopes"] = sorted(
+            ([k, v] for k, v in w.scope_ms.items()),
+            key=lambda kv: -kv[1])[:10]
+    w.round_host_ms = w.stages["round_host_ms"]
 
 
 class Tracer:
@@ -314,7 +370,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
         f"{', '.join(mapped.not_taken) or 'none'}")
     w = Window(cell=name, spec=spec, mix=mix,
                seconds=float(seconds), traced=trace, peaks=peaks,
-               row_bytes=ROW_BYTES[cfg.ess.host_cache_dtype])
+               row_bytes=row_bytes(spec, cfg.ess.host_cache_dtype))
     params = weights.make(abstract_params(T.model_def(cfg)), seed)
     jax.block_until_ready(params)
     log(f"weights ready at {time.perf_counter() - T_START:.2f}s")
@@ -326,6 +382,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
                     mtp_depth=mapped.mtp_depth)
     tracer = Tracer(trace, float(mix.get("trace_seconds", seconds)))
     rec = drive_closed(eng, reqs, w, tracer, SamplingParams)
+    maps = stage_maps(eng) if trace else {}
     w.tokens_in_window = sum(
         1 for ts in w.token_times.values() for t in ts if w.t0 <= t <= w.t1)
     stats = dev.memory_stats() or {}
@@ -340,18 +397,15 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
 
     if trace:
         import trace_reduce as tr
-        dev_ops, host = tr.load(tracer.dir)
-        red = tr.reduce_events(dev_ops, host)
-        w.busy_s, w.window_s = red.busy_s, red.window_s
-        w.breakdown = {"device_ops": red.device_ops,
-                       "idle_gaps": red.idle_gaps}
-        shutil.rmtree(tracer.dir, ignore_errors=True)
+        read_trace(w, tr.newest(tracer.dir), maps)
 
     metrics = {}
     for m in cell_metrics(bench, name, trace):
         v = load_reader(m["name"])(w)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    if trace:
+        shutil.rmtree(tracer.dir, ignore_errors=True)
 
     # correctness: the served tokens (with --control 1 the control's)
     # against the plain reference

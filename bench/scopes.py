@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import bisect
 import gzip
+import heapq
 from collections import defaultdict
 from typing import NamedTuple
 
@@ -97,30 +98,57 @@ def window_of(spans: list[Interval]) -> tuple[float, float]:
     return wins[0].start, wins[0].end
 
 
+def self_times(ops: list[tuple[float, float, float]]) -> list[float]:
+    """Each op's own share of the instants that ``ops`` (``(start, end,
+    length)``, clipped to a window, with the op's unclipped length)
+    cover: an instant that several ops cover goes to the shortest of them,
+    so that a ``while`` or ``conditional`` op, whose event spans the ops of
+    its body, keeps only the time between them.  The shares add up to the
+    union of the ops."""
+    edges = sorted([(s, 1, i) for i, (s, _, _) in enumerate(ops)]
+                   + [(e, 0, i) for i, (_, e, _) in enumerate(ops)])
+    own = [0.0] * len(ops)
+    active: list[tuple[float, int]] = []       # heap of (length, op)
+    ended: set[int] = set()
+    prev = None
+    for t, opens, i in edges:
+        while active and active[0][1] in ended:
+            heapq.heappop(active)
+        if active and prev is not None:
+            own[active[0][1]] += t - prev
+        prev = t
+        if opens:
+            heapq.heappush(active, (ops[i][2], i))
+        else:
+            ended.add(i)
+    return own
+
+
 def scope_seconds(trace: Trace, maps: dict[str, dict[str, str]],
                   module: str = DECODE_MODULE, top: int = 4) -> dict:
     """Device seconds of each scope over the ops of ``module`` inside the
-    window, with ``unmapped`` (ops whose name the module's map lacks)
-    counted apart, the total, and each scope's ``top`` ops by seconds."""
+    window, each instant counted once (:func:`self_times`), with
+    ``unmapped`` (ops whose name the module's map lacks) counted apart,
+    the total, and each scope's ``top`` ops by seconds."""
     w0, w1 = window_of(trace.spans)
     table = maps.get(module, {})
+    names, clipped = [], []
+    for e, mod in trace.ops:
+        s, t = max(e.start, w0), min(e.end, w1)
+        if mod == module and t > s:
+            names.append(e.name)
+            clipped.append((s, t, e.end - e.start))
     sec: dict[str, float] = defaultdict(float)
     per_op: dict[str, float] = defaultdict(float)
-    unmapped_n, unmapped_s, total = 0, 0.0, 0.0
-    for e, mod in trace.ops:
-        if mod != module:
-            continue
-        d = min(e.end, w1) - max(e.start, w0)
-        if d <= 0:
-            continue
-        total += d
-        sc = table.get(e.name)
+    unmapped_n, unmapped_s = 0, 0.0
+    for name, d in zip(names, self_times(clipped)):
+        sc = table.get(name)
         if sc is None:
             unmapped_n += 1
             unmapped_s += d
         else:
             sec[sc] += d
-            per_op[e.name] += d
+            per_op[name] += d
     ops: dict[str, list] = defaultdict(list)
     for name, d in sorted(per_op.items(), key=lambda kv: -kv[1]):
         if len(ops[table[name]]) < top:
@@ -128,7 +156,7 @@ def scope_seconds(trace: Trace, maps: dict[str, dict[str, str]],
     return {"scopes": {k: v / 1e9 for k, v in sorted(sec.items())},
             "top_ops": dict(sorted(ops.items())),
             "unmapped_ops": unmapped_n, "unmapped_s": unmapped_s / 1e9,
-            "total_s": total / 1e9,
+            "total_s": sum(sec.values(), unmapped_s) / 1e9,
             "module_runs": sum(1 for m in trace.modules
                                if m.name == module and w0 <= m.start < w1)}
 

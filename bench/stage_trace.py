@@ -7,14 +7,13 @@ run of a benchmark cell.
 The run is ``bench/run.py``'s own traced run (``--trace 1``: weights from
 the seed, the cell's traffic, warm-up, a profiler window of the traffic's
 ``trace_seconds``, the reference check), and its result line is printed
-as that script prints it.  Besides, while the engine is alive after the
-window, each round program that ran in the window is mapped onto its
-``ess.*`` scopes (lowered and compiled again on the same arguments: a
-compile-cache hit), and the window's trace is reduced by
-``bench/scopes.py``: device milliseconds per round of each scope, the
-device's idle gaps named by the program's host spans, and the host's own
-milliseconds per round.  That reduction is the second JSON line.  With
-``--out`` the trace (gzipped) and the scope maps are written there.
+as that script prints it.  That run maps the decode program onto its
+``ess.*`` scopes and reduces the window's trace by ``bench/scopes.py``;
+this script prints the whole reduction as the second JSON line: device
+milliseconds per round of each scope and its largest ops, the device's
+idle gaps named by the program's host spans, and the host's own
+milliseconds per round.  With ``--out`` the trace (gzipped) and the scope
+maps are written there.
 
 ``--tiny`` runs the tiny CPU-test cell of ``bench/tests/tiny.py`` instead,
 with a 0.1 s window (the small recorded trace under ``bench/tests/data``
@@ -25,7 +24,6 @@ Without a TPU it exits 1.
 from __future__ import annotations
 
 import argparse
-import glob
 import gzip
 import json
 import os
@@ -58,50 +56,43 @@ def round_spans_us(n: int = 20000) -> float:
 
 
 class Capture:
-    """Wraps ``run.drive_closed``: after the window, keeps the trace and
-    maps the decode program's instructions onto their scopes."""
+    """Wraps ``run.drive_closed`` and ``run.stage_maps``: keeps the Window
+    (whose ``stages`` the run fills from the trace), a copy of the
+    window's trace (the run deletes its own) and the scope maps."""
 
-    def __init__(self, drive):
-        self.drive = drive
+    def __init__(self, drive, maps):
+        self._drive, self._maps = drive, maps
+        self.w = None
         self.trace = None          # path of the kept .xplane.pb
         self.maps = {}             # module name -> instruction -> scope
-        self.rounds = 0
         self.keep = tempfile.mkdtemp(prefix="stage_trace_")
 
-    def __call__(self, eng, reqs, w, tracer, SP):
-        from repro.analysis.hlo_scopes import op_scopes
-        rec = self.drive(eng, reqs, w, tracer, SP)
-        paths = glob.glob(os.path.join(tracer.dir, "**", "*.xplane.pb"),
-                          recursive=True)
+    def drive(self, eng, reqs, w, tracer, SP):
+        import trace_reduce
+        rec = self._drive(eng, reqs, w, tracer, SP)
         self.trace = os.path.join(self.keep, "trace.xplane.pb")
-        shutil.copy(max(paths, key=os.path.getmtime), self.trace)
-        s = eng.session
-        fn = s._programs.spec(True) if s.mtp_depth > 0 \
-            else s._programs.decode(True)
-        t = time.perf_counter()
-        mod, table = op_scopes(fn.lower(s.params, s.state).compile()
-                               .as_text())
-        run.log(f"stage_trace: {mod} mapped ({len(table)} instructions) "
-                f"in {time.perf_counter() - t:.2f}s")
-        self.maps[mod] = table
-        self.rounds = w.trace_counts.steps
+        shutil.copy(trace_reduce.newest(tracer.dir), self.trace)
+        self.w = w
         return rec
 
+    def stage_maps(self, eng):
+        self.maps = self._maps(eng)
+        return self.maps
 
-def stage_report(cap: Capture, module: str) -> dict:
+
+def stage_report(w) -> dict:
     import scopes
-    red = scopes.reduce(scopes.load(cap.trace), cap.maps, module)
-    n = max(cap.rounds, 1)
+    red = w.stages
+    n = max(w.trace_counts.steps, 1)
     dev = red["device"]
-    per_round = {k: v / n * 1e3 for k, v in dev["scopes"].items()}
     total = dev["total_s"]
     idle = red["idle"]
     named = sum(v for k, v in idle["by_span"].items()
                 if k.startswith(scopes.PREFIX))
     return {
-        "rounds": cap.rounds, "module": module,
+        "rounds": w.trace_counts.steps,
         "module_runs": dev["module_runs"],
-        "scope_ms_per_round": per_round,
+        "scope_ms_per_round": w.scope_ms,
         "top_ops_ms_per_round": {
             k: [[nm, d / n * 1e3] for nm, d in v]
             for k, v in dev["top_ops"].items()},
@@ -151,14 +142,13 @@ def main(argv=None) -> int:
     else:
         bench = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
         name, root, data = args.workload, ROOT, HERE
-    cap = Capture(run.drive_closed)
-    run.drive_closed = cap
+    cap = Capture(run.drive_closed, run.stage_maps)
+    run.drive_closed, run.stage_maps = cap.drive, cap.stage_maps
     out = run.run_cell(bench, name, args.seed,
                        float(bench.get("run_seconds", 10)), True, root=root,
                        data=data)
     print(json.dumps(out), flush=True)
-    from repro.analysis.contracts import ROUND_MODULES
-    rep = stage_report(cap, next(iter(cap.maps), ROUND_MODULES["decode"]))
+    rep = stage_report(cap.w)
     with jax.profiler.trace(tempfile.mkdtemp(prefix="stage_on_")):
         rep["round_spans_us_on"] = round_spans_us()
     print(json.dumps(rep), flush=True)

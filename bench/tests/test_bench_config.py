@@ -27,7 +27,7 @@ def _spec(entry):
 def test_mapped_widths_equal_the_file(entry):
     spec = _spec(entry)
     cfg = config_map.to_program(spec).cfg
-    for key, (path, width) in config_map.KEY_MAP.items():
+    for key, (path, width) in config_map.key_map(spec).items():
         if key not in spec:
             continue
         obj = cfg

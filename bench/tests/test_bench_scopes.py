@@ -79,6 +79,32 @@ def test_scope_seconds_read_the_decode_module_alone():
     assert dev["module_runs"] == 1
 
 
+def test_a_loop_op_and_its_body_are_counted_once():
+    """A ``while`` op's event spans the ops of its body: each instant goes
+    to the innermost op, the loop keeps the time between its body's ops,
+    and the scopes add up to the module's busy time."""
+    ops = [(Interval("while.3", 10, 50), "jit_decode_round"),
+           (Interval("fusion.4", 12, 30), "jit_decode_round"),
+           (Interval("fusion.5", 32, 48), "jit_decode_round"),
+           (Interval("copy.6", 40, 45), "jit_decode_round"),
+           (Interval("fusion.7", 50, 60), "jit_decode_round")]
+    mods = [Interval("jit_decode_round", 10, 60)]
+    spans = [Interval("bench.window", 0, 100)]
+    maps = {"jit_decode_round": {"while.3": "ess.topk",
+                                 "fusion.4": "ess.topk",
+                                 "fusion.5": "ess.topk",
+                                 "copy.6": "unscoped",
+                                 "fusion.7": "ess.pool"}}
+    dev = scopes.scope_seconds(scopes.Trace(ops, mods, spans), maps)
+    assert dev["scopes"] == {"ess.topk": pytest.approx(35e-9),
+                             "unscoped": pytest.approx(5e-9),
+                             "ess.pool": pytest.approx(10e-9)}
+    assert dev["total_s"] == pytest.approx(50e-9)
+    assert dev["top_ops"]["ess.topk"] == [["fusion.4", pytest.approx(18e-9)],
+                                          ["fusion.5", pytest.approx(11e-9)],
+                                          ["while.3", pytest.approx(6e-9)]]
+
+
 def test_idle_gaps_and_round_host_time():
     trace, _ = _synthetic()
     idle = scopes.idle_gaps(trace)
@@ -133,13 +159,15 @@ def test_recorded_chip_trace_with_program_spans():
     red = scopes.reduce(trace, maps)
     dev = red["device"]
     assert dev["module_runs"] == 6 and dev["unmapped_ops"] == 0
-    assert dev["total_s"] == pytest.approx(8.50049e-3, rel=1e-6)
+    # each instant once: the six ``conditional`` ops of the head (3.43 us)
+    # span the unscoped copies of their bodies, which keep that time
+    assert dev["total_s"] == pytest.approx(8.49706e-3, rel=1e-6)
     assert sum(dev["scopes"].values()) == pytest.approx(dev["total_s"])
     want = {"ess.pool": 4.856867e-3, "ess.miss_gather": 1.9601e-3,
             "ess.topk": 1.195232e-3, "ess.spill": 1.63532e-4,
             "ess.attend": 1.17088e-4, "ess.ffn": 1.0004e-4,
             "ess.indexer": 5.1567e-5, "unscoped": 2.516e-05,
-            "ess.head": 2.3273e-5, "ess.embed": 7.631e-6}
+            "ess.head": 1.9843e-5, "ess.embed": 7.631e-6}
     assert dev["scopes"] == {k: pytest.approx(v, rel=1e-6)
                              for k, v in want.items()}
     idle = red["idle"]

@@ -139,15 +139,21 @@ def op_name(event_name: str) -> str:
     return event_name.split(" = ", 1)[0].lstrip("%")
 
 
-def load(trace_dir: str) -> tuple[dict[str, list[Interval]], list[Interval]]:
-    """Device op events and host ``bench.*`` spans of the newest
-    ``.xplane.pb`` under ``trace_dir`` (or of that file itself)."""
-    from jax.profiler import ProfileData
+def newest(trace_dir: str) -> str:
+    """The newest ``.xplane.pb`` under ``trace_dir`` (or that file
+    itself)."""
     paths = [trace_dir] if trace_dir.endswith(".xplane.pb") else glob.glob(
         os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    pd = ProfileData.from_file(max(paths, key=os.path.getmtime))
+    return max(paths, key=os.path.getmtime)
+
+
+def load(trace_dir: str) -> tuple[dict[str, list[Interval]], list[Interval]]:
+    """Device op events and host ``bench.*`` spans of the newest
+    ``.xplane.pb`` under ``trace_dir`` (or of that file itself)."""
+    from jax.profiler import ProfileData
+    pd = ProfileData.from_file(newest(trace_dir))
     dev: dict[str, list[Interval]] = {}
     modules: dict[str, list[float]] = {}
     host: list[Interval] = []
