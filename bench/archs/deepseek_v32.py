@@ -2,7 +2,11 @@
 the program takes, the plain reference and the operation counts.
 
 ``KEY_MAP`` adds the architecture's keys to ``bench/config_map.py``'s
-``COMMON`` table: MLA, the DSA indexer and the MoE.
+``COMMON`` table: MLA, the DSA indexer and the MoE.  ``NOT_TAKEN`` names
+the published keys the program does not take yet (the configuration
+file's ``departures``); the configuration tests hold each file of this
+``model_type`` to it.  The weights are the program's layout, so the module
+adds nothing to ``bench/weights.py``'s leaf tables.
 
 ``Reference`` is the layer equations in float32 ``jax.numpy``
 (``bench/reference.py``'s ``Base``).  It follows the configuration file's
@@ -61,6 +65,10 @@ KEY_MAP: dict[str, tuple[str, bool]] = {
     "moe_layer_freq": ("moe.layer_freq", False),
     "ep_size": ("moe.ep_size", False),
 }
+# published keys the program does not take yet: the grouped router's
+# settings, its scoring function, yarn and the declared context
+NOT_TAKEN = ("n_group", "topk_group", "topk_method", "rope_scaling",
+             "scoring_func", "max_position_embeddings")
 
 
 class Reference(Base):

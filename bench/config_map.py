@@ -10,6 +10,13 @@ later change adds the field under that name, the key is applied with no
 edit here.  A width must equal the field it maps onto, or the run fails.
 Keys absent from both tables are reported as not taken too.
 
+A table may name a leaf of a nested group by dotted key
+(``rope_parameters.rope_theta``).  A group with a mapped leaf is walked
+and each unmapped leaf is reported by its dotted key; a group with none
+is one key, as any other.  A JSON list reaches the program as a tuple and
+a JSON object as a tuple of its sorted ``(key, value)`` pairs, so the
+program's frozen config stays hashable.
+
 ``serve`` holds the engine settings that are not model keys: the
 program's registered architecture (``arch``), the ESS options
 (``serve.ess`` -> ``cfg.ess``) and the MTP depth served.
@@ -58,8 +65,9 @@ def _load_arch(path: str):
 
 def arch_module(spec: dict):
     """The module ``<ARCHS>/<model_type>.py`` of a configuration file: its
-    ``KEY_MAP``, its ``Reference`` (``bench/reference.py``) and its
-    ``Model`` (``bench/flops.py``)."""
+    ``KEY_MAP`` and ``NOT_TAKEN``, its ``Reference``
+    (``bench/reference.py``), its ``Model`` (``bench/flops.py``) and any
+    additions to ``bench/weights.py``'s leaf tables."""
     if "model_type" not in spec:
         raise KeyError("the configuration file has no model_type")
     path = os.path.join(ARCHS, spec["model_type"] + ".py")
@@ -97,6 +105,28 @@ def _coerce(old, new):
     return new
 
 
+def frozen(value):
+    """``value`` with every JSON list made a tuple and every JSON object a
+    tuple of its sorted ``(key, value)`` pairs."""
+    if isinstance(value, list):
+        return tuple(frozen(v) for v in value)
+    if isinstance(value, dict):
+        return tuple(sorted((k, frozen(v)) for k, v in value.items()))
+    return value
+
+
+def _items(table: dict, key: str, value):
+    """The ``(published key, value)`` pairs of one key of the file: a group
+    with a leaf in ``table`` is walked by dotted key, anything else is one
+    pair."""
+    if isinstance(value, dict) and any(k.startswith(key + ".")
+                                       for k in table):
+        for sub, v in value.items():
+            yield from _items(table, f"{key}.{sub}", v)
+    else:
+        yield key, value
+
+
 def _set(cfg, path: str, value, width: bool, key: str):
     """Return ``cfg`` with field ``path`` set to ``value``, or None when
     the program's dataclass has no such field."""
@@ -123,18 +153,19 @@ def to_program(spec: dict) -> Mapped:
     cfg = get_config(serve["arch"])
     table = key_map(spec)
     not_taken = []
-    for key, value in spec.items():
-        if key in META:
+    for top, group in spec.items():
+        if top in META:
             continue
-        if key not in table:
-            not_taken.append(key)
-            continue
-        path, width = table[key]
-        new = _set(cfg, path, value, width, key)
-        if new is None:
-            not_taken.append(key)
-        else:
-            cfg = new
+        for key, value in _items(table, top, group):
+            if key not in table:
+                not_taken.append(key)
+                continue
+            path, width = table[key]
+            new = _set(cfg, path, frozen(value), width, key)
+            if new is None:
+                not_taken.append(key)
+            else:
+                cfg = new
     ess = serve.get("ess")
     if ess:
         cfg = dataclasses.replace(

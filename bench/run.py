@@ -371,7 +371,8 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool,
     w = Window(cell=name, spec=spec, mix=mix,
                seconds=float(seconds), traced=trace, peaks=peaks,
                row_bytes=row_bytes(spec, cfg.ess.host_cache_dtype))
-    params = weights.make(abstract_params(T.model_def(cfg)), seed)
+    params = weights.make(abstract_params(T.model_def(cfg)), seed,
+                          config_map.arch_module(spec))
     jax.block_until_ready(params)
     log(f"weights ready at {time.perf_counter() - T_START:.2f}s")
     reqs = traffic.generate(mix, seed, cfg.vocab_size)

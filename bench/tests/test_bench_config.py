@@ -1,4 +1,7 @@
-"""Configuration files against the program's config and BENCHMARK.json."""
+"""Configuration files against the program's config and BENCHMARK.json,
+each held to its own architecture module: every file of ``bench/configs``,
+and the GLM-5.2 catalog entry through a stand-in ``glm_moe_dsa`` module
+(``glm_stub.py``)."""
 
 import glob
 import json
@@ -7,6 +10,7 @@ import os
 import pytest
 
 import config_map
+import glm_stub
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -19,30 +23,57 @@ CONFIGS = [next((c for c in BENCH["configs"] if c["file"] == rel),
                os.path.join(ROOT, "bench", "configs", "*.json")))]
 
 
+CASES = CONFIGS + [glm_stub.ENTRY]
+# keys every catalog entry shares, taken wherever the file's table maps them
+SHARED = ("hidden_size", "index_topk", "n_routed_experts")
+
+
 def _spec(entry):
     return config_map.load(os.path.join(ROOT, entry["file"]))
 
 
-@pytest.mark.parametrize("entry", CONFIGS, ids=lambda e: e["name"])
-def test_mapped_widths_equal_the_file(entry):
-    spec = _spec(entry)
+@pytest.fixture
+def spec_of(tmp_path, monkeypatch):
+    """The configuration file of a case; the stand-in's case installs its
+    module first."""
+    def get(entry):
+        if entry["name"] == glm_stub.ENTRY["name"]:
+            return glm_stub.install(tmp_path, monkeypatch, config_map)
+        return _spec(entry)
+    return get
+
+
+def _published(spec, key):
+    """The file's value at a (dotted) published key, or KeyError."""
+    for part in key.split("."):
+        spec = spec[part]
+    return spec
+
+
+@pytest.mark.parametrize("entry", CASES, ids=lambda e: e["name"])
+def test_mapped_widths_equal_the_file(entry, spec_of):
+    spec = spec_of(entry)
     cfg = config_map.to_program(spec).cfg
-    for key, (path, width) in config_map.key_map(spec).items():
-        if key not in spec:
+    table = config_map.key_map(spec)
+    for key, (path, width) in table.items():
+        try:
+            value = _published(spec, key)
+        except KeyError:
             continue
         obj = cfg
         for part in path.split("."):
             obj = getattr(obj, part, None)
         if obj is not None:
-            assert obj == spec[key], (key, path, obj)
+            assert obj == config_map.frozen(value), (key, path, obj)
     assert cfg.num_layers == spec["num_hidden_layers"]
-    assert cfg.moe.num_experts == spec["n_routed_experts"]
+    if "n_routed_experts" in table:
+        assert cfg.moe.num_experts == spec["n_routed_experts"]
     assert cfg.ess.host_cache_dtype == spec["serve"]["ess"]["host_cache_dtype"]
 
 
-@pytest.mark.parametrize("entry", CONFIGS, ids=lambda e: e["name"])
-def test_reduced_names_exactly_the_changed_keys(entry):
-    spec = _spec(entry)
+@pytest.mark.parametrize("entry", CASES, ids=lambda e: e["name"])
+def test_reduced_names_exactly_the_changed_keys(entry, spec_of):
+    spec = spec_of(entry)
     published = spec["published"]
     for key, value in published.items():
         assert spec[key] != value, key
@@ -51,14 +82,16 @@ def test_reduced_names_exactly_the_changed_keys(entry):
         assert entry["source"] == spec["source"]
 
 
-@pytest.mark.parametrize("entry", CONFIGS, ids=lambda e: e["name"])
-def test_not_taken_keys_are_reported(entry):
-    got = config_map.to_program(_spec(entry)).not_taken
-    for key in ("n_group", "topk_group", "topk_method", "rope_scaling",
-                "scoring_func", "max_position_embeddings"):
+@pytest.mark.parametrize("entry", CASES, ids=lambda e: e["name"])
+def test_not_taken_keys_are_reported(entry, spec_of):
+    spec = spec_of(entry)
+    got = config_map.to_program(spec).not_taken
+    for key in config_map.arch_module(spec).NOT_TAKEN:
         assert key in got
-    for key in ("hidden_size", "index_topk", "n_routed_experts"):
-        assert key not in got
+    table = config_map.key_map(spec)
+    for key in SHARED:
+        if key in table:
+            assert key not in got
 
 
 def test_a_changed_width_fails():

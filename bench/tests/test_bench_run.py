@@ -41,7 +41,8 @@ def test_reference_matches_the_program_dense_forward(topk):
     from repro.models.params import abstract_params
     spec = dict(tiny.CONFIG, index_topk=topk)
     cfg = config_map.to_program(spec).cfg
-    params = weights.make(abstract_params(T.model_def(cfg)), 5)
+    params = weights.make(abstract_params(T.model_def(cfg)), 5,
+                          config_map.arch_module(spec))
     toks = np.random.default_rng(0).integers(0, 256, 40).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)

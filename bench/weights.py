@@ -10,6 +10,12 @@ Scales: every projection has unit-variance outputs for unit-variance
 inputs (std = 1 / sqrt(contracted size)); the embedding has unit
 variance; the output head 1 / sqrt(hidden); norm gains (stored as
 ``1 + w``) and the router bias are zero.
+
+The leaf names below are the program's.  An architecture module
+(``bench/archs/<model_type>.py``) whose weights the program lays out
+otherwise adds to them by defining tuples of the same names: ``STACKS``
+(top-level groups whose leaves carry a leading layer dimension), ``ZERO``,
+``CONTRACT_FIRST`` and ``EXPERT``.
 """
 
 from __future__ import annotations
@@ -35,31 +41,36 @@ def key_for(seed: int) -> jax.Array:
         jnp.asarray([s >> 32, s & 0xFFFFFFFF], jnp.uint32))
 
 
-def leaf_std(path: tuple, shape: tuple) -> float | None:
-    """Std of a leaf's draw, or None for a zero leaf."""
+def leaf_std(path: tuple, shape: tuple, arch) -> float | None:
+    """Std of a leaf's draw, or None for a zero leaf; ``arch`` is the
+    architecture module, whose tables add to this module's."""
+    def names_of(table: str, base: tuple) -> tuple:
+        return base + tuple(getattr(arch, table, ()))
+
     names = [getattr(k, "key", str(k)) for k in path]
     name = names[-1]
-    if name in ZERO:
+    if name in names_of("ZERO", ZERO):
         return None
     if name == "embed":
         return 1.0
-    s = shape[1:] if names[0] in STACKS else shape
+    s = shape[1:] if names[0] in names_of("STACKS", STACKS) else shape
     if name == "unembed":
         return 1.0 / math.sqrt(s[-1])
-    if name in CONTRACT_FIRST:
+    if name in names_of("CONTRACT_FIRST", CONTRACT_FIRST):
         fan = s[0]
-    elif name in EXPERT:
+    elif name in names_of("EXPERT", EXPERT):
         fan = s[1]
     else:
         fan = int(np.prod(s[:-1]))
     return 1.0 / math.sqrt(fan)
 
 
-def make(abstract, seed: int):
+def make(abstract, seed: int, arch):
     """Materialize ``abstract`` (a tree of ShapeDtypeStructs) from
-    ``seed`` in one jitted call."""
+    ``seed`` in one jitted call; ``arch`` is the architecture module
+    (``config_map.arch_module``)."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract)
-    plan = [(leaf_std(path, tuple(a.shape)), a.shape, a.dtype)
+    plan = [(leaf_std(path, tuple(a.shape), arch), a.shape, a.dtype)
             for path, a in leaves]
 
     @jax.jit
